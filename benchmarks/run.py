@@ -185,6 +185,8 @@ def main() -> None:
                              "analyze", "guard", "kernels", "roofline"])
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.quick:
         suite = QUICK_SUITE
     elif args.full:

@@ -221,7 +221,9 @@ def cholesky(
             sym, None, device_engine, max_batch=max_batch, staging=staging,
             store=store, **gkw,
         )
-        return F if guard == "off" else _attach_guard(F, A, guard, gval)
+        if guard != "off":
+            F = _attach_guard(F, A, guard, gval)
+        return _refine_low_precision(F, A)
     if sym is None:
         sym, Aperm = symbolic_pipeline(
             A, ordering=ordering, merge=merge, refine=refine, max_growth=max_growth
@@ -261,7 +263,22 @@ def cholesky(
         rep.broken.append({"supernode": None, "level": None,
                            "min_pivot": float("nan"), "nonfinite": False})
         raise BreakdownError(rep, f"Cholesky breakdown: {e}") from e
-    return F if guard == "off" else _attach_guard(F, A, guard, gval)
+    if guard != "off":
+        F = _attach_guard(F, A, guard, gval)
+    return _refine_low_precision(F, A)
+
+
+def _low_precision(dstore) -> bool:
+    return dstore is not None and np.dtype(dstore.dtype).itemsize < 8
+
+
+def _refine_low_precision(F: CholeskyFactor, A) -> CholeskyFactor:
+    """A float32 device factor is inexact for ``A``: record ``A`` as the
+    refinement target, so solves refine to float64 accuracy as they do for
+    a perturbed factor."""
+    if F.guard_A is None and _low_precision(F.dstore):
+        F.guard_A = sp.csc_matrix(A)
+    return F
 
 
 def _attach_guard(F: CholeskyFactor, A, guard: str, val) -> CholeskyFactor:
@@ -433,6 +450,8 @@ def cholesky_many(
                 sp.csc_matrix(Ai) if rep.needs_refine else None
                 for Ai, rep in zip(As, BF.guard_reports)
             ]
+    if _low_precision(BF.dstore):
+        BF.guard_As = [sp.csc_matrix(Ai) for Ai in As]
     return BF
 
 

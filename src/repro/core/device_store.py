@@ -24,21 +24,17 @@ two structural facts of level-scheduled right-looking factorization:
 
 So update matrices go to a preallocated device *pool* (packed real entries,
 one contiguous ``dynamic_update_slice`` per group), and when a group is
-gathered its pending contributions are applied by the prefix-sum trick: with
-the incoming pool entries gathered in destination order, the per-cell sums
-are ``C[hi] - C[lo]`` of the running sum — gathers only.  Factored panels
-are packed per group (a gather) and concatenated once into the contiguous
-device factor the solve programs index.
+gathered its pending contributions are applied as per-cell sums: with the
+incoming pool entries gathered in destination order, one sorted segment sum
+(``engines.segment_sums``) adds each cell's entries on their own, so each
+sum's rounding error is relative to that cell's contributions.  (The
+difference of two prefixes of one running sum, ``C[hi] - C[lo]``, would
+carry error relative to the whole group's total, which cancels in
+float32.)  Factored panels are packed per group (a gather) and concatenated
+once into the contiguous device factor the solve programs index.
 
-Precision caveat: a segment sum recovered as a difference of prefixes
-carries absolute error proportional to the running total's magnitude, not
-the segment's, so update entries whose magnitudes differ by many orders
-within one group's incoming slice (badly scaled mixed-unit systems) lose
-accuracy relative to direct per-segment summation.  On the benchmark suite
-this costs ~one digit of residual (4e-13 -> ~2e-12); exact segmented or
-compensated summation for ill-scaled inputs is a ROADMAP follow-up —
-pre-scale such systems (e.g. Jacobi/diagonal equilibration) in the
-meantime.
+Values are stored on the device as the engine's dtype (``eng.dtype``,
+float32 on TPU); the host copies stay float64.
 
 Index plans
 -----------
@@ -51,11 +47,14 @@ LevelSchedule:
                       per lane)
     src (n,)          pool position of every incoming update entry, sorted
                       by destination packed cell
-    lo / hi (r,)      segment bounds of each packed cell's contributions
+    lo / hi (r,)      segment bounds of each packed cell's contributions (only
+                      ``hi`` is staged: ``lo`` is ``hi`` shifted by one)
     gidx (Bp,Lp,Wp)   index into the zero/one-extended packed vector that
                       reproduces the stacked padded panel buffer (pad cells
                       -> the zero cell r, identity diagonals -> the one cell
-                      r+1)
+                      r+1); host-side only — the device programs rebuild it
+                      from ``pstart``/extents (``engines.panel_index``)
+    pstart (Bp,)      packed start of each lane's cells within the group
     ppack (r,)        position in the factored (Bp,Lp,Wp) buffer of each
                       real cell (packs the factored panels)
     upack (n_out,)    position in the (Bp,mp,mp) update buffer of each real
@@ -76,12 +75,14 @@ rows finalized by earlier, higher-level steps).
 from __future__ import annotations
 
 from dataclasses import dataclass
+from functools import partial
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import counters
-from repro.core.engines import _bucket_batch
+from repro.core.engines import _bucket_batch, panel_index
 from repro.core.relind import scatter_plan
 from repro.core.schedule import LevelSchedule
 from repro.core.symbolic import SymbolicFactor
@@ -100,15 +101,26 @@ class GroupIndices:
     lb: int                # slice start within the level's packed chunk
     cells: np.ndarray      # (r,)
     src: np.ndarray        # (n,)
-    lo: np.ndarray         # (r,)
     hi: np.ndarray         # (r,)
-    gidx: np.ndarray       # (Bp, Lp, Wp)
     ppack: np.ndarray      # (r,)
     upack: np.ndarray      # (n_out,)
     rows_arr: np.ndarray   # (Bp,) true row count per lane (pad lanes 0)
     ws_arr: np.ndarray     # (Bp,) true width per lane (pad lanes 0)
+    pstart: np.ndarray     # (Bp,) packed start of each lane (pad lanes 0)
     cols: np.ndarray       # (Bp, Wp)
     tails: np.ndarray      # (Bp, Lp-Wp)
+
+    @property
+    def lo(self) -> np.ndarray:
+        """(r,) segment starts: ``hi`` shifted right by one."""
+        return np.concatenate([np.zeros(1, self.hi.dtype), self.hi[:-1]])
+
+    @property
+    def gidx(self) -> np.ndarray:
+        """(Bp, Lp, Wp) padded-layout gather index, built on demand (the
+        device programs build the same from ``pstart`` and the extents)."""
+        return panel_index(np, self.pstart, self.rows_arr, self.ws_arr,
+                           self.Lp, self.Wp, self.cells.shape[0])
 
 
 @dataclass
@@ -141,7 +153,6 @@ def build_device_plan(sym: SymbolicFactor, sched: LevelSchedule) -> DeviceGroupP
 
     # pass 1: per-supernode placement (group id, packed base of its lane)
     flat_groups = [bg for lg in sched.groups for bg in lg]
-    gid_of_super = np.empty(sym.nsuper, dtype=np.int64)
     packed_start = np.empty(sym.nsuper, dtype=np.int64)  # global packed base
     group_base = np.zeros(len(flat_groups) + 1, dtype=np.int64)
     pos = 0
@@ -149,7 +160,6 @@ def build_device_plan(sym: SymbolicFactor, sched: LevelSchedule) -> DeviceGroupP
         group_base[gi] = pos
         for s in bg.ids:
             s = int(s)
-            gid_of_super[s] = gi
             packed_start[s] = pos
             pos += sym.rows[s].shape[0] * sym.width(s)
     group_base[-1] = pos
@@ -157,7 +167,6 @@ def build_device_plan(sym: SymbolicFactor, sched: LevelSchedule) -> DeviceGroupP
 
     # pass 2: pool layout + every update entry's destination (packed slot)
     pool_off = np.zeros(len(flat_groups) + 1, dtype=np.int64)
-    dest_gid: list = []
     dest_pos: list = []
     for gi, bg in enumerate(flat_groups):
         cnt = 0
@@ -171,19 +180,19 @@ def build_device_plan(sym: SymbolicFactor, sched: LevelSchedule) -> DeviceGroupP
             dcell = plan.dst[s].reshape(m, m)[il, jl].astype(np.int64)
             # destination supernode of each entry -> its packed slot
             a = np.searchsorted(offs, dcell, side="right") - 1
-            dest_gid.append(gid_of_super[a])
-            dest_pos.append(packed_start[a] + (dcell - offs[a]))
+            dest_pos.append((packed_start[a] + (dcell - offs[a])).astype(idx_t))
             cnt += il.shape[0]
         pool_off[gi + 1] = pool_off[gi] + cnt
     pool_size = int(pool_off[-1])
-    dest_gid = np.concatenate(dest_gid) if dest_gid else np.empty(0, np.int64)
-    dest_pos = np.concatenate(dest_pos) if dest_pos else np.empty(0, np.int64)
-    # incoming entries of each group, sorted by destination packed slot
-    order = np.lexsort((dest_pos, dest_gid))
-    sorted_gid = dest_gid[order]
+    dest_pos = np.concatenate(dest_pos) if dest_pos else np.empty(0, idx_t)
+    # incoming entries of each group, sorted by destination packed slot:
+    # each group's slots are one contiguous range, in group order, so the
+    # slot order is also the group order (stable: ties keep pool order)
+    order = np.argsort(dest_pos, kind="stable")
     sorted_pos = dest_pos[order]
-    grp_lo = np.searchsorted(sorted_gid, np.arange(len(flat_groups)))
-    grp_hi = np.searchsorted(sorted_gid, np.arange(len(flat_groups)), side="right")
+    del dest_pos
+    grp_lo = np.searchsorted(sorted_pos, group_base[:-1])
+    grp_hi = np.searchsorted(sorted_pos, group_base[1:])
 
     # pass 3: per-group index arrays
     out: list = []
@@ -200,15 +209,13 @@ def build_device_plan(sym: SymbolicFactor, sched: LevelSchedule) -> DeviceGroupP
             Bp = _bucket_batch(B)
             base = int(group_base[gi])
             r = int(group_base[gi + 1] - base)
-            gidx = np.full((Bp, Lp, Wp), r, dtype=idx_t)      # r = the zero cell
-            d = np.arange(Wp)
-            gidx[B:, d, d] = r + 1                             # pad lanes: identity
             cols = np.full((Bp, Wp), n, dtype=idx_t)
             tails = np.full((Bp, mp), n, dtype=idx_t)
             cells = np.empty(r, dtype=idx_t)
             ppack = np.empty(r, dtype=idx_t)
             rows_arr = np.zeros(Bp, dtype=np.int32)  # pad lanes stay (0, 0):
             ws_arr = np.zeros(Bp, dtype=np.int32)    # the masked kernel skips them
+            pstart = np.zeros(Bp, dtype=idx_t)
             upacks = []
             p = 0
             for i, s in enumerate(bg.ids):
@@ -219,6 +226,7 @@ def build_device_plan(sym: SymbolicFactor, sched: LevelSchedule) -> DeviceGroupP
                 m = rows.shape[0] - w
                 rows_arr[i] = rows.shape[0]
                 ws_arr[i] = w
+                pstart[i] = p
                 sz = rows.shape[0] * w
                 cells[p:p + sz] = offs[s] + np.arange(sz)
                 # padded row of each real row: diag rows stay, tail rows jump
@@ -229,9 +237,6 @@ def build_device_plan(sym: SymbolicFactor, sched: LevelSchedule) -> DeviceGroupP
                 cgrid = np.arange(w)
                 pp = ((i * Lp + prow)[:, None] * Wp + cgrid).ravel()
                 ppack[p:p + sz] = pp
-                gidx.reshape(-1)[pp] = p + np.arange(sz)
-                dd = np.arange(w, Wp)
-                gidx[i, dd, dd] = r + 1
                 cols[i, :w] = f + np.arange(w)
                 if m:
                     tails[i, :m] = rows[w:]
@@ -243,16 +248,15 @@ def build_device_plan(sym: SymbolicFactor, sched: LevelSchedule) -> DeviceGroupP
                      if upacks else np.empty(0, dtype=idx_t))
             src = order[grp_lo[gi]:grp_hi[gi]].astype(idx_t)
             pp_in = sorted_pos[grp_lo[gi]:grp_hi[gi]] - base
-            counts = np.bincount(pp_in, minlength=r)
-            hi = np.cumsum(counts).astype(idx_t)
-            lo = (hi - counts).astype(idx_t)
+            hi = np.cumsum(np.bincount(pp_in, minlength=r)).astype(idx_t)
             lvl_out.append(GroupIndices(
                 level=bg.level, Lp=Lp, Wp=Wp, B=B, Bp=Bp,
                 base=base, off=int(pool_off[gi]),
                 lb=int(base - level_base[bg.level]),
-                cells=cells, src=src, lo=lo, hi=hi, gidx=gidx,
+                cells=cells, src=src, hi=hi,
                 ppack=ppack, upack=upack,
-                rows_arr=rows_arr, ws_arr=ws_arr, cols=cols, tails=tails,
+                rows_arr=rows_arr, ws_arr=ws_arr, pstart=pstart,
+                cols=cols, tails=tails,
             ))
             gi += 1
         out.append(lvl_out)
@@ -261,6 +265,27 @@ def build_device_plan(sym: SymbolicFactor, sched: LevelSchedule) -> DeviceGroupP
         groups=out, cells_concat=cells_concat, level_base=level_base,
         packed_total=packed_total, pool_size=pool_size,
     )
+
+
+@partial(jax.jit, static_argnums=1)
+def _split(flat, pieces: tuple):
+    """Slice the one staged index upload into its per-group arrays in ONE
+    program (``pieces`` = ((offset, shape), ...), static, so a repeat
+    pattern reuses the compiled split): eager slices would compile one tiny
+    program per distinct (offset, size)."""
+    out = []
+    for off, shape in pieces:
+        n = int(np.prod(shape))
+        out.append(flat[off:off + n].reshape(shape))
+    return out
+
+
+@jax.jit
+def _concat_flat(xs):
+    """Concatenate along the last axis, each array flattened past the
+    leading (matrix) axes of the first (one program per input structure)."""
+    lead = xs[0].shape[:-1]
+    return jnp.concatenate([x.reshape(lead + (-1,)) for x in xs], axis=-1)
 
 
 def device_plan(sym: SymbolicFactor, sched: LevelSchedule) -> DeviceGroupPlan:
@@ -273,20 +298,27 @@ def device_plan(sym: SymbolicFactor, sched: LevelSchedule) -> DeviceGroupPlan:
 
 
 class _DevGroup:
-    """One group's index arrays as device-resident buffers."""
-    __slots__ = ("cells", "src", "lo", "hi", "gidx", "ppack", "upack",
+    """One group's index arrays as device-resident buffers, plus its static
+    bucket shape (Bp, Lp, Wp) and packed cell count r."""
+    __slots__ = ("cells", "src", "hi", "pstart", "ppack", "upack",
                  "rows", "ws", "cols", "tails", "off", "base", "lb",
-                 "P", "Dinv")
+                 "Bp", "Lp", "Wp", "r", "P", "Dinv")
 
-    def __init__(self, cells, src, lo, hi, gidx, ppack, upack, rows, ws,
-                 cols, tails, off, base, lb):
-        self.cells, self.src, self.lo, self.hi = cells, src, lo, hi
-        self.gidx, self.ppack, self.upack = gidx, ppack, upack
+    def __init__(self, cells, src, hi, pstart, ppack, upack, rows, ws,
+                 cols, tails, off, base, lb, Bp, Lp, Wp, r):
+        self.cells, self.src, self.hi = cells, src, hi
+        self.pstart, self.ppack, self.upack = pstart, ppack, upack
         self.rows, self.ws = rows, ws
         self.cols, self.tails = cols, tails
         self.off, self.base, self.lb = off, base, lb
+        self.Bp, self.Lp, self.Wp, self.r = Bp, Lp, Wp, r
         self.P = None     # stacked padded factored panels (built at finalize)
         self.Dinv = None  # inverted diagonal blocks (built at finalize)
+
+    def drop_assembly(self) -> None:
+        """Free the factorization-only index buffers (the solve keeps
+        extents, cols and tails)."""
+        self.cells = self.src = self.hi = self.ppack = self.upack = None
 
 
 class DevicePanelStore:
@@ -363,40 +395,42 @@ class DevicePanelStore:
         self.staging = staging
         # one staged upload of every group's index arrays, device-side slicing
         if factored:
-            kinds = ("gidx", "cols", "tails")
+            kinds = ("pstart", "rows_arr", "ws_arr", "cols", "tails")
         elif self.fused:  # the fused program never indexes raw storage cells
-            kinds = ("src", "lo", "hi", "gidx", "ppack", "upack",
+            kinds = ("src", "hi", "pstart", "ppack", "upack",
                      "rows_arr", "ws_arr", "cols", "tails")
         else:
-            kinds = ("cells", "src", "lo", "hi", "gidx", "ppack", "upack",
+            kinds = ("cells", "src", "hi", "pstart", "ppack", "upack",
                      "rows_arr", "ws_arr", "cols", "tails")
         parts = [getattr(g, k).ravel()
                  for lvl in gp.groups for g in lvl for k in kinds]
         flat = np.concatenate(parts) if parts else np.zeros(0, dtype=np.int32)
+        pieces, pos = [], 0
+        for lvl in gp.groups:
+            for g in lvl:
+                for k in kinds:
+                    a = getattr(g, k)
+                    pieces.append((pos, a.shape))
+                    pos += a.size
         dflat = eng.put(flat)
+        split = iter(_split(dflat, tuple(pieces)))
+        empty = jnp.zeros(0, dflat.dtype)
         self.groups: list = []
-        pos = 0
         for lvl in gp.groups:
             row = []
             for g in lvl:
-                devs = {}
-                for k in kinds:
-                    a = getattr(g, k)
-                    devs[k] = dflat[pos:pos + a.size].reshape(a.shape)
-                    pos += a.size
-                empty = dflat[0:0]
+                devs = {k: next(split) for k in kinds}
                 row.append(_DevGroup(
                     cells=devs.get("cells", empty),
                     src=devs.get("src", empty),
-                    lo=devs.get("lo", empty),
                     hi=devs.get("hi", empty),
-                    gidx=devs["gidx"],
+                    pstart=devs["pstart"],
                     ppack=devs.get("ppack", empty),
                     upack=devs.get("upack", empty),
-                    rows=devs.get("rows_arr", empty),
-                    ws=devs.get("ws_arr", empty),
+                    rows=devs["rows_arr"], ws=devs["ws_arr"],
                     cols=devs["cols"], tails=devs["tails"],
                     off=g.off, base=g.base, lb=g.lb,
+                    Bp=g.Bp, Lp=g.Lp, Wp=g.Wp, r=int(g.cells.shape[0]),
                 ))
             self.groups.append(row)
         self.factor_ext = None
@@ -410,23 +444,24 @@ class DevicePanelStore:
         self.trash = None
         self._iperm = None
         self._operm = None
+        dt = self.dtype = np.dtype(getattr(eng, "dtype", np.float64))
         if factored:
             # stage the already-factored panels, packed (one transfer)
             if self.nmat > 1:
-                packed = np.empty((self.nmat, gp.packed_total + 2))
+                packed = np.empty((self.nmat, gp.packed_total + 2), dtype=dt)
                 packed[:, :-2] = host_storage[:, gp.cells_concat]
                 packed[:, -2:] = (0.0, 1.0)
             else:
-                packed = np.empty(gp.packed_total + 2, dtype=np.float64)
+                packed = np.empty(gp.packed_total + 2, dtype=dt)
                 packed[:-2] = host_storage[gp.cells_concat]
                 packed[-2:] = (0.0, 1.0)
             self.factor_ext = eng.put(packed)
             return
         pool_shape = ((self.nmat, gp.pool_size) if self.nmat > 1
                       else (gp.pool_size,))
-        self.pool = jnp.zeros(pool_shape, dtype=jnp.float64)
+        self.pool = jnp.zeros(pool_shape, dtype=dt)
         if not self.fused:
-            self.storage0 = eng.put(host_storage)
+            self.storage0 = eng.put(host_storage.astype(dt))
             return
         # fused staging: the raw storage packed in group (= level) order, so
         # each level's cells are one contiguous chunk and a group's slice is
@@ -434,7 +469,7 @@ class DevicePanelStore:
         lb = gp.level_base
         nlev = len(gp.groups)
         if staging == "sync":
-            whole = eng.put(host_storage[..., gp.cells_concat])
+            whole = eng.put(host_storage[..., gp.cells_concat].astype(dt))
             self._chunks = [whole[..., lb[l]:lb[l + 1]] for l in range(nlev)]
         else:
             # keep the raw storage and gather each level's cells lazily at
@@ -457,7 +492,9 @@ class DevicePanelStore:
         eng = self.eng
         gp = self.plan
         cells = gp.cells_concat[gp.level_base[lvl]:gp.level_base[lvl + 1]]
-        self._chunks[lvl] = eng.put(self._host_storage[..., cells])
+        self._chunks[lvl] = eng.put(
+            self._host_storage[..., cells].astype(self.dtype)
+        )
         if hasattr(eng, "_event"):
             eng._event("upload", lvl)
 
@@ -490,17 +527,20 @@ class DevicePanelStore:
         factor the solve programs read (device op, no transfer)."""
         if self.factor_ext is not None:
             return
+        tail = jnp.array([0.0, 1.0], dtype=self.dtype)
         if self.nmat > 1:
-            tail = jnp.tile(jnp.array([0.0, 1.0]), (self.nmat, 1))
+            tail = jnp.tile(tail, (self.nmat, 1))
             self.factor_ext = jnp.concatenate(self._packed + [tail], axis=1)
         else:
-            tail = jnp.concatenate([jnp.zeros(1), jnp.ones(1)])
             self.factor_ext = jnp.concatenate(self._packed + [tail])
         self._packed = []
         self.storage0 = None
         self.pool = None
         self._chunks = []
         self._host_storage = None
+        for row in self.groups:
+            for dg in row:
+                dg.drop_assembly()
 
     def ensure_solve_ready(self) -> None:
         """Lazy solve preparation (first device solve only — factor-only
@@ -539,30 +579,19 @@ class DevicePanelStore:
         and solve no triangular systems — they read the resident P/Dinv
         buffers and run batched GEMMs, at the cost of one extra padded copy
         of the factor on the device."""
+        eng = self.eng
         total = self.plan.packed_total
         n, M = self.sym.n, self.nmat
-        for lvl, lgroups in enumerate(self.plan.groups):
-            for gi, g in enumerate(lgroups):
-                dg = self.groups[lvl][gi]
-                r = g.cells.shape[0]
-                sgidx = jnp.where(
-                    dg.gidx < r, dg.gidx + g.base, dg.gidx - r + total
-                )
-                if M > 1:
-                    # M factors stack into one (M*Bp, ...) panel batch; each
-                    # matrix's RHS rows live in its own (n+1) block, so the
-                    # per-lane column/tail targets shift by mi*(n+1) (the
-                    # shared pad target n lands on each matrix's OWN trash)
-                    Bp = dg.gidx.shape[0]
-                    dg.P = self.factor_ext[:, sgidx].reshape(
-                        M * Bp, g.Lp, g.Wp
-                    )
-                    shift = (jnp.arange(M) * (n + 1))[:, None, None]
-                    dg.cols = (dg.cols[None] + shift).reshape(M * Bp, -1)
-                    dg.tails = (dg.tails[None] + shift).reshape(M * Bp, -1)
-                else:
-                    dg.P = self.factor_ext[sgidx]
-                dg.Dinv = self.eng.invert_diag(dg.P)
+        # M factors stack into one (M*Bp, ...) panel batch; each matrix's
+        # RHS rows live in its own (n+1) block, so the per-lane column/tail
+        # targets shift by mi*(n+1) (the shared pad target n lands on each
+        # matrix's OWN trash row)
+        for dg in (dg for row in self.groups for dg in row):
+            dg.P, dg.cols, dg.tails = eng._panels_fn(
+                M, dg.Bp, dg.Lp, dg.Wp, dg.r
+            )(self.factor_ext, dg.pstart, dg.rows, dg.ws, dg.cols, dg.tails,
+              dg.base, total, n + 1)
+            dg.Dinv = eng.invert_diag(dg.P)
 
     def read_into(self, host_storage: np.ndarray) -> None:
         """One bulk device->host transfer of the (factored) packed panels.
@@ -571,12 +600,8 @@ class DevicePanelStore:
         self.finalize()
         nf = self.factor_ext.shape[-1]
         if self._status:
-            if self.nmat > 1:
-                flat = [s.reshape(self.nmat, -1) for s in self._status]
-            else:
-                flat = [s.reshape(-1) for s in self._status]
             blob = self.eng.get(
-                jnp.concatenate([self.factor_ext] + flat, axis=-1)
+                _concat_flat([self.factor_ext] + self._status)
             )
             packed, self._status_host = blob[..., :nf], blob[..., nf:]
             self._status = []
@@ -595,7 +620,7 @@ class DevicePanelStore:
         pos = 0
         for row in self.groups:
             for dg in row:
-                Bp = dg.gidx.shape[0]
+                Bp = dg.Bp
                 k = Bp * 4
                 if self.nmat > 1:
                     out.append(
@@ -660,18 +685,18 @@ def device_solve(dstore: DevicePanelStore, b) -> np.ndarray:
         y = y[..., None]
     k = y.shape[-1]
     if M > 1:
-        yp = np.zeros((M, n + 1, k))
+        yp = np.zeros((M, n + 1, k), dtype=dstore.dtype)
         yp[:, :n] = y[:, sym.perm]
         dy = eng.put(yp.reshape(M * (n + 1), k))
-        z = eng.get(_solve_levels(dstore, dy))
+        z = eng.get(_solve_levels(dstore, dy)).astype(np.float64)
         z = z.reshape(M, n + 1, k)[:, :n]
         x = np.empty_like(z)
         x[:, sym.perm] = z
     else:
-        yp = np.zeros((n + 1, k))
+        yp = np.zeros((n + 1, k), dtype=dstore.dtype)
         yp[:n] = y[sym.perm]
         dy = eng.put(yp)
-        z = eng.get(_solve_levels(dstore, dy))[:n]
+        z = eng.get(_solve_levels(dstore, dy))[:n].astype(np.float64)
         x = np.empty_like(z)
         x[sym.perm] = z
     return x[..., 0] if squeeze else x

@@ -40,7 +40,115 @@ import numpy as np
 
 from repro.core.guard import GFLOOR_MULT
 from repro.kernels import ops as kops
-from repro.kernels.fused import fused_factor_syrk
+from repro.kernels.fused import fits_vmem, fused_factor_syrk
+
+_HI = jax.lax.Precision.HIGHEST
+
+
+class DispatchFault(RuntimeError):
+    """A runtime fault of one group dispatch that a re-run on the next tier
+    of the fallback chain may get past.  It is the only error the chain
+    absorbs: a lowering, compile or out-of-memory error propagates.
+    Nothing in the engine raises it; ``repro.faults`` injects it."""
+
+
+def _mm(a, b):
+    """Device matmul at full float32 precision (the TPU default for float32
+    operands is a single bfloat16 pass)."""
+    return jnp.matmul(a, b, precision=_HI)
+
+
+#: most rows one scatter writes in the level-solve programs: the TPU
+#: compiler takes 3-16 s on a row scatter of 16384 or more indices, and
+#: well under 1 s on each piece of 8192 (compiling for v5e)
+SCATTER_ROWS = 8192
+
+
+def scatter_rows(y, idx, vals, add: bool = False):
+    """``y.at[idx].set(vals)`` (or ``.add``) over rows of ``y``, issued in
+    pieces of at most ``SCATTER_ROWS`` indices, in order."""
+    for i in range(0, idx.shape[0], SCATTER_ROWS):
+        ref = y.at[idx[i:i + SCATTER_ROWS]]
+        piece = vals[i:i + SCATTER_ROWS]
+        y = ref.add(piece) if add else ref.set(piece)
+    return y
+
+
+def trsm_rlt(L, B, nb: int = 128):
+    """X with ``X @ L.T = B``: L lower triangular (W, W), B (m, W) — the
+    TRSM of a panel's tail below its factored diagonal block.
+
+    XLA's ``triangular_solve`` unrolls over W/128 blocks on TPU, so its
+    compile time grows fast with W (about 7 s at W = 512 and 22 s at 1024,
+    compiling for v5e).  Past one block this loops over ``nb``-column
+    blocks instead: each step subtracts the solved columns' contribution
+    (unsolved columns of X are still zero) and solves one (nb, nb) block,
+    so the compiled program is the same size whatever W."""
+    W = L.shape[0]
+    if W <= nb or W % nb:
+        return jax.lax.linalg.triangular_solve(
+            L, B, left_side=False, lower=True, transpose_a=True
+        )
+
+    def step(k, X):
+        k0 = k * nb
+        Lrow = jax.lax.dynamic_slice_in_dim(L, k0, nb, axis=0)      # (nb, W)
+        Lkk = jax.lax.dynamic_slice_in_dim(Lrow, k0, nb, axis=1)    # (nb, nb)
+        rhs = jax.lax.dynamic_slice_in_dim(B, k0, nb, axis=1) - _mm(X, Lrow.T)
+        xk = jax.lax.linalg.triangular_solve(
+            Lkk, rhs, left_side=False, lower=True, transpose_a=True
+        )
+        return jax.lax.dynamic_update_slice_in_dim(X, xk, k0, axis=1)
+
+    return jax.lax.fori_loop(jnp.int32(0), jnp.int32(W // nb), step,
+                             jnp.zeros_like(B))
+
+
+def panel_index(xp, pstart, rows, ws, Lp: int, Wp: int, r: int):
+    """(Bp, Lp, Wp) gather index of a group's stacked padded panels into its
+    zero/one-extended packed cell vector (``r`` = the zero cell, ``r + 1``
+    = the one cell), computed from the per-lane packed start, row count
+    and width (pad lanes: rows = w = 0, an identity panel).  ``xp`` is
+    numpy or jax.numpy: the device programs build it on the fly instead of
+    staging a padded-size index array."""
+    it = pstart.dtype
+    ri = xp.arange(Lp, dtype=it)[None, :, None]
+    ci = xp.arange(Wp, dtype=it)[None, None, :]
+    w = ws.astype(it)[:, None, None]
+    m = rows.astype(it)[:, None, None] - w
+    p = pstart[:, None, None]
+    diag = (ri < w) & (ci < w)
+    tail = (ri >= Wp) & (ri < Wp + m) & (ci < w)
+    prow = xp.where(ri < Wp, ri, ri - Wp + w)   # row within the true panel
+    idx = xp.where(diag | tail, p + prow * w + ci, r)
+    return xp.where((ri == ci) & (ri >= w), r + 1, idx).astype(it)
+
+
+def segment_sums(vals, hi):
+    """Per-cell sums of destination-sorted update entries along the last
+    axis: cell c owns ``vals[..., lo[c]:hi[c]]`` with ``lo`` = ``hi``
+    shifted right by one.  Each cell's entries are added on their own (a
+    sorted segment sum), so a sum's rounding error is relative to that
+    cell's contributions.  (A difference of two prefixes of one running
+    sum, ``C[hi] - C[lo]``, is relative to the group's whole running total
+    instead: it costs about a digit in float64 and cancels outright in
+    float32.)"""
+    n = vals.shape[-1]
+    r = hi.shape[0]
+    # segment id of every entry: the first cell whose end lies past it
+    # (empty cells are skipped).  A binary search, not a cumsum of segment
+    # starts: the TPU compiler takes 2-26 s on a cumsum of 1e5-1e7
+    # entries, against about 1 s on this search.
+    seg = jnp.searchsorted(hi, jnp.arange(n, dtype=hi.dtype), side="right",
+                           method="scan")
+
+    def one(v):
+        return jax.ops.segment_sum(v, seg, num_segments=r,
+                                   indices_are_sorted=True)
+
+    for _ in range(vals.ndim - 1):
+        one = jax.vmap(one)
+    return one(vals)
 
 
 def _bucket(x: int, base: int = 128) -> int:
@@ -145,11 +253,14 @@ def bucket_shape_fused(rows: int, w: int) -> tuple[int, int]:
     beyond-the-tail SYRK tiles outright, so padding costs memory but not
     flops.  That inverts ``bucket_shape_batch``'s trade: COARSER buckets are
     strictly better — fewer program shapes to compile, bigger batches per
-    dispatch.  Plain powers of two keep ``Lp - Wp`` a multiple of ``Wp``'s
-    base, so the kernel's SYRK tile (gcd with 128) stays MXU-friendly.
+    dispatch.  The width ``Wp`` and the tail ``mp = Lp - Wp`` are each a
+    power of two (base 8): a tail of 128 rows or more is then a multiple
+    of 128, so the kernel's SYRK tile is 128 wide and its u block stays
+    ``(mp, 128)``; a shorter tail is one full-width tile.
     """
     Wp = _bucket_pow2(w, 8)
-    return _bucket_pow2(Wp + rows - w, 16), Wp
+    m = rows - w
+    return Wp + (_bucket_pow2(m, 8) if m else 0), Wp
 
 
 def _host_lane_factor(buf: np.ndarray, rows: int, w: int, Wp: int,
@@ -228,22 +339,28 @@ class DeviceEngine:
 
     backend      'xla' (jnp ops — MAGMA-analogue device BLAS), 'pallas'
                  (the fused Pallas supernode kernel + per-op kernels;
-                 interpret on CPU), or None — resolve like the kernel ops
-                 do (REPRO_KERNEL_BACKEND, else 'pallas' on TPU, 'xla'
-                 elsewhere)
+                 interpret on CPU), or None (default) — resolve like the
+                 kernel ops do (REPRO_KERNEL_BACKEND, else 'pallas' on TPU,
+                 'xla' elsewhere).  Under 'pallas', a group whose bucket
+                 exceeds the kernel's VMEM bound runs the XLA group program
+                 (``fits_vmem``), decided from the shape before dispatch.
     fused        factor the panel in ONE device call (beyond-paper: the
                  paper issues DPOTRF and DTRSM separately)
     fused_groups device-resident path: run each (level x bucket) group as
                  ONE dispatch (gather + apply updates + factor + pack fused
                  into a single program) instead of three; False keeps the
                  three-dispatch PR 2 pipeline as the oracle
+
+    Values live on the device as ``kops.device_dtype()`` (``self.dtype``):
+    float32 on TPU, float64 elsewhere.
     """
 
     name = "device"
 
-    def __init__(self, backend: str | None = "xla", fused: bool = True,
+    def __init__(self, backend: str | None = None, fused: bool = True,
                  fused_groups: bool = True, events_cap: int = 4096):
         self.backend = backend if backend is not None else kops.default_backend()
+        self.dtype = np.dtype(kops.device_dtype())
         self.fused = fused
         self.fused_groups = fused_groups
         self.stats = {"transfers_in": 0, "transfers_out": 0,
@@ -286,6 +403,17 @@ class DeviceEngine:
         # Deliberately NOT in ``stats``: callers assert exact equality on
         # that dict, and these only move when a dispatch tier fails.
         self.fallbacks = {"xla": 0, "host": 0, "failed": 0}
+        # fused-group dispatches by the program that ran them ('pallas' =
+        # the compiled kernel, 'xla' = the vmapped group program)
+        self.group_programs = {"pallas": 0, "xla": 0}
+
+    def uses_kernel(self, Lp: int, Wp: int, *, guard: bool = False) -> bool:
+        """Shape routing: True when a (Lp, Wp) bucket runs the fused Pallas
+        kernel, False when it runs the XLA program (xla backend, or a
+        bucket past the kernel's VMEM bound)."""
+        return self.backend == "pallas" and fits_vmem(
+            Lp, Wp, self.dtype.itemsize, guard=guard
+        )
 
     def _event(self, tag: str, lvl: int) -> None:
         if (self.events.maxlen is not None
@@ -348,10 +476,10 @@ class DeviceEngine:
     def _factor_syrk_fn(self, Lp: int, Wp: int):
         """Fused factor + update-matrix program: one round trip per supernode.
 
-        Under ``backend='pallas'`` this routes through the single fused
-        Pallas kernel (repro.kernels.fused) with the panel's true extents;
-        the xla path chains the factor and SYRK programs (still one jit)."""
-        if self.backend == "pallas":
+        A bucket that ``uses_kernel`` runs the single fused Pallas kernel
+        (repro.kernels.fused) with the panel's true extents; any other runs
+        the XLA factor + SYRK chain (one jit).  Both take (p, rows, w)."""
+        if self.uses_kernel(Lp, Wp):
 
             def fp_(p, rows, w):
                 fp, u = fused_factor_syrk(
@@ -363,14 +491,9 @@ class DeviceEngine:
                 return fp[0], u[0]
 
             return self._program(("factor_syrk", Lp, Wp), lambda: jax.jit(fp_))
-        factor = self._factor_fn(Lp, Wp)
-        syrk = self._syrk_tail_fn(Lp, Wp)
-
-        def f(p):
-            fp = factor(p)
-            return fp, syrk(fp)
-
-        return self._program(("factor_syrk", Lp, Wp), lambda: jax.jit(f))
+        one = self._one_factor_syrk(Lp, Wp)
+        return self._program(("factor_syrk", Lp, Wp),
+                             lambda: jax.jit(lambda p, rows, w: one(p)))
 
     @staticmethod
     def _slice_rows(p, start, npad, n):
@@ -406,35 +529,27 @@ class DeviceEngine:
         return self._program(("gemm_block", Lp, Wp, nrp, ncp), lambda: jax.jit(f))
 
     def _one_factor_syrk(self, Lp: int, Wp: int):
-        """Per-panel fused POTRF+TRSM+SYRK (traced under vmap by the batched
-        factor and device-resident assembly programs).  Returns (factored
-        panel, update matrix); the update is (Lp-Wp, Lp-Wp) with only the
-        lower triangle meaningful ((0, 0) when the bucket has no tail)."""
-        backend = self.backend
+        """Per-panel POTRF+TRSM+SYRK in XLA (traced under vmap by the XLA
+        group programs).  Returns (factored panel, update matrix); the
+        update is (Lp-Wp, Lp-Wp) with only the lower triangle meaningful
+        ((0, 0) when the bucket has no tail)."""
 
         def one(p):
-            if backend == "pallas":
-                fp = kops.factor_panel(p, Wp, backend="pallas")
+            # Panels store only the lower triangle (upper is zero).  The
+            # scalar LAPACK lowering never reads the upper part, but the
+            # BATCHED cholesky lowering does — mirror the strict lower
+            # triangle to make the input symmetric before factoring.
+            a = p[:Wp, :Wp]
+            a = a + jnp.tril(a, -1).T
+            ld = jax.lax.linalg.cholesky(a, symmetrize_input=False)
+            if Lp > Wp:
+                fp = jnp.concatenate([ld, trsm_rlt(ld, p[Wp:])], axis=0)
             else:
-                # Panels store only the lower triangle (upper is zero).  The
-                # scalar LAPACK lowering never reads the upper part, but the
-                # BATCHED cholesky lowering does — mirror the strict lower
-                # triangle to make the input symmetric before factoring.
-                a = p[:Wp, :Wp]
-                a = a + jnp.tril(a, -1).T
-                ld = jax.lax.linalg.cholesky(a, symmetrize_input=False)
-                if Lp > Wp:
-                    x = jax.lax.linalg.triangular_solve(
-                        ld, p[Wp:], left_side=False, lower=True, transpose_a=True
-                    )
-                    fp = jnp.concatenate([ld, x], axis=0)
-                else:
-                    fp = ld
+                fp = ld
             if Lp == Wp:
                 return fp, jnp.zeros((0, 0), p.dtype)
             b = fp[Wp:]
-            u = kops.syrk_ln(b, backend="pallas") if backend == "pallas" else b @ b.T
-            return fp, u
+            return fp, _mm(b, b.T)
 
         return one
 
@@ -454,8 +569,8 @@ class DeviceEngine:
         mp = Lp - Wp
 
         def status_of(fp, rows, w, mind2, ncl, mag):
-            rI = jnp.arange(Lp)[:, None]
-            cI = jnp.arange(Wp)[None, :]
+            rI = jnp.arange(Lp, dtype=jnp.int32)[:, None]
+            cI = jnp.arange(Wp, dtype=jnp.int32)[None, :]
             m = rows - w
             live = ((rI < w) & (cI < w) & (rI >= cI)) | (
                 (rI >= Wp) & (rI < Wp + m) & (cI < w)
@@ -468,7 +583,7 @@ class DeviceEngine:
             if mp == 0:
                 return jnp.zeros((0, 0), p.dtype)
             b = fp[Wp:]
-            return b @ b.T
+            return _mm(b, b.T)
 
         if not clamp:
 
@@ -477,15 +592,12 @@ class DeviceEngine:
                 a = a + jnp.tril(a, -1).T
                 ld = jax.lax.linalg.cholesky(a, symmetrize_input=False)
                 if Lp > Wp:
-                    x = jax.lax.linalg.triangular_solve(
-                        ld, p[Wp:], left_side=False, lower=True,
-                        transpose_a=True
-                    )
-                    fp = jnp.concatenate([ld, x], axis=0)
+                    fp = jnp.concatenate([ld, trsm_rlt(ld, p[Wp:])], axis=0)
                 else:
                     fp = ld
                 dk = jnp.diagonal(ld)
-                d2 = jnp.where(jnp.arange(Wp) < w, dk * dk, jnp.inf)
+                d2 = jnp.where(jnp.arange(Wp, dtype=jnp.int32) < w, dk * dk,
+                               jnp.asarray(jnp.inf, p.dtype))
                 mind2 = jnp.min(d2)  # NaN-propagating on breakdown
                 zero = jnp.zeros((), p.dtype)
                 return fp, tail_u(fp, p), status_of(
@@ -497,8 +609,8 @@ class DeviceEngine:
         def one(p, rows, w, thr):
             # explicit right-looking column loop with the sign-flipping
             # clamp — mirrors kernels/fused.py col_body exactly
-            rI = jnp.arange(Lp)[:, None]
-            cI = jnp.arange(Wp)[None, :]
+            rI = jnp.arange(Lp, dtype=jnp.int32)[:, None]
+            cI = jnp.arange(Wp, dtype=jnp.int32)[None, :]
             m = rows - w
             keep = ((rI < w) & (cI < w)) | (
                 (rI >= Wp) & (rI < Wp + m) & (cI < w)
@@ -518,8 +630,8 @@ class DeviceEngine:
                 # growth floor theta^2 * BETA / thr = theta^2 / max|diag|
                 # (see kernels/fused.py col_body for the derivation)
                 theta = jnp.max(jnp.where(rI > k, jnp.abs(colk), 0.0))
-                gfloor = theta * theta * (GFLOOR_MULT
-                                          / jnp.maximum(thr, 1e-300))
+                gfloor = theta * theta * (
+                    GFLOOR_MULT / jnp.maximum(thr, jnp.finfo(p.dtype).tiny))
                 cl = real & (thr > 0) & (
                     jnp.logical_not(d2 >= thr)
                     | jnp.logical_not(d2 >= gfloor)
@@ -535,12 +647,12 @@ class DeviceEngine:
                 below = jnp.where(rI > k, colk, 0.0)
                 lcol = jnp.where(rI == k, dk, below)
                 bd = jnp.where(cI > k, below[:Wp].reshape(1, Wp), 0.0)
-                a = a - below @ bd
+                a = a - _mm(below, bd)
                 return jnp.where(cI == k, lcol, a), mind2, ncl, mag
 
             zero = jnp.zeros((), p.dtype)
             fp, mind2, ncl, mag = jax.lax.fori_loop(
-                0, Wp, col_step,
+                jnp.int32(0), jnp.int32(Wp), col_step,
                 (a, jnp.full((), jnp.inf, p.dtype), zero, zero)
             )
             return fp, tail_u(fp, p), status_of(fp, rows, w, mind2, ncl, mag)
@@ -548,14 +660,14 @@ class DeviceEngine:
         return one
 
     def _batch_factor_syrk_fn(self, Bp: int, Lp: int, Wp: int):
-        """Batched fused program — ONE dispatch per (level, bucket) batch.
-        Under ``backend='pallas'`` the whole batch runs as a single fused
-        Pallas kernel taking the true per-lane extents (pad lanes and ragged
-        tails are masked, not computed); the xla path vmaps the per-panel
-        POTRF+TRSM+SYRK chain.  Returns (factored panels, update matrices);
-        the update output is (Bp, Lp-Wp, Lp-Wp) with only the lower triangle
-        meaningful (the pallas path zeroes the rest)."""
-        if self.backend == "pallas":
+        """Batched fused program — ONE dispatch per (level, bucket) batch,
+        taking (panels, rows, ws).  A bucket that ``uses_kernel`` runs as a
+        single fused Pallas kernel taking the true per-lane extents (pad
+        lanes and ragged tails are masked, not computed); any other vmaps
+        the per-panel XLA POTRF+TRSM+SYRK chain.  Returns (factored panels,
+        update matrices); the update output is (Bp, Lp-Wp, Lp-Wp) with only
+        the lower triangle meaningful (the kernel zeroes the rest)."""
+        if self.uses_kernel(Lp, Wp):
 
             def f(p, rows, ws):
                 return fused_factor_syrk(p, rows, ws, interpret=kops._interpret())
@@ -563,9 +675,10 @@ class DeviceEngine:
             return self._program(
                 ("batch_factor_syrk", Bp, Lp, Wp), lambda: jax.jit(f)
             )
-        one = self._one_factor_syrk(Lp, Wp)
+        one = jax.vmap(self._one_factor_syrk(Lp, Wp))
         return self._program(
-            ("batch_factor_syrk", Bp, Lp, Wp), lambda: jax.jit(jax.vmap(one))
+            ("batch_factor_syrk", Bp, Lp, Wp),
+            lambda: jax.jit(lambda p, rows, ws: one(p)),
         )
 
     # -- device-resident programs (see repro.core.device_store) -------------
@@ -577,9 +690,9 @@ class DeviceEngine:
     # instead each group's real update entries are packed (a gather) into a
     # preallocated device *pool* (a contiguous dynamic_update_slice), and
     # when an ancestor group is later gathered, its pending contributions are
-    # summed by destination cell via prefix sums: with the group's incoming
-    # pool entries gathered in destination order, segment sums are
-    # C[hi]-C[lo] of the cumulative sum — gathers again.  Factored panels are
+    # summed by destination cell: with the group's incoming pool entries
+    # gathered in destination order, one sorted segment sum
+    # (``segment_sums``) yields every cell's sum.  Factored panels are
     # likewise never written back to flat storage: they are packed (a gather)
     # per group and concatenated at the end into the device-resident factor
     # the solve programs read.  All index arrays are host-precomputed
@@ -589,16 +702,16 @@ class DeviceEngine:
         storage and the update pool: storage gather, contribution segment
         sums, zero/one extension, padded-layout gather."""
 
-        def f(storage0, pool, cells, src, lo, hi, gidx):
+        def f(storage0, pool, cells, src, hi, pstart, rows, ws):
             pc = storage0[cells]  # (r,) the group's panel cells, packed
             if n:
-                vals = pool[src]  # incoming update entries, destination-sorted
-                C = jnp.concatenate([jnp.zeros(1, pool.dtype), jnp.cumsum(vals)])
-                pc = pc - (C[hi] - C[lo])
+                # incoming update entries, destination-sorted
+                pc = pc - segment_sums(pool[src], hi)
             ext = jnp.concatenate(
                 [pc, jnp.zeros(1, pc.dtype), jnp.ones(1, pc.dtype)]
             )
-            return ext[gidx]  # (Bp, Lp, Wp) stacked padded panels
+            # (Bp, Lp, Wp) stacked padded panels
+            return ext[panel_index(jnp, pstart, rows, ws, Lp, Wp, r)]
 
         return self._program(
             ("gather_group", Bp, Lp, Wp, r, n), lambda: jax.jit(f)
@@ -639,22 +752,22 @@ class DeviceEngine:
         traced ``thr`` — while guard=False compiles the exact pre-guard
         program, so guard="off" keeps zero detection overhead.  ``clamp``
         (static) enables pivot perturbation at ``thr`` in the factor body.
-        ``backend`` overrides the engine backend (the fallback chain retries
-        a failed pallas group through the xla program)."""
+        ``backend`` picks the factor program: 'pallas' (the kernel) or
+        'xla', as ``_run_group_chain`` routes the bucket."""
         backend = backend or self.backend
         one = self._one_factor_syrk(Lp, Wp)
         one_g = self._one_factor_syrk_guarded(Lp, Wp, clamp) if guard else None
 
-        def gather(chunk, pool, lb, gidx, src, lo, hi):
+        def gather(chunk, pool, lb, src, hi, pstart, rows, ws):
             pc = jax.lax.dynamic_slice(chunk, (lb,), (r,))
             if n_in:
-                vals = pool[src]  # incoming update entries, destination-sorted
-                C = jnp.concatenate([jnp.zeros(1, pool.dtype), jnp.cumsum(vals)])
-                pc = pc - (C[hi] - C[lo])
+                # incoming update entries, destination-sorted
+                pc = pc - segment_sums(pool[src], hi)
             ext = jnp.concatenate(
                 [pc, jnp.zeros(1, pc.dtype), jnp.ones(1, pc.dtype)]
             )
-            return ext[gidx]  # (Bp, Lp, Wp) stacked padded panels
+            # (Bp, Lp, Wp) stacked padded panels
+            return ext[panel_index(jnp, pstart, rows, ws, Lp, Wp, r)]
 
         def pack(fp, u, pool, ppack, upack, off):
             packed = fp.reshape(-1)[ppack]
@@ -666,9 +779,9 @@ class DeviceEngine:
 
         if guard:
 
-            def f(chunk, pool, lb, off, src, lo, hi, gidx, rows, ws,
+            def f(chunk, pool, lb, off, src, hi, pstart, rows, ws,
                   ppack, upack, thr):
-                buf = gather(chunk, pool, lb, gidx, src, lo, hi)
+                buf = gather(chunk, pool, lb, src, hi, pstart, rows, ws)
                 if backend == "pallas":
                     fp, u, st = fused_factor_syrk(
                         buf, rows, ws, interpret=kops._interpret(),
@@ -683,9 +796,9 @@ class DeviceEngine:
 
         else:
 
-            def f(chunk, pool, lb, off, src, lo, hi, gidx, rows, ws,
+            def f(chunk, pool, lb, off, src, hi, pstart, rows, ws,
                   ppack, upack):
-                buf = gather(chunk, pool, lb, gidx, src, lo, hi)
+                buf = gather(chunk, pool, lb, src, hi, pstart, rows, ws)
                 if backend == "pallas":
                     fp, u = fused_factor_syrk(
                         buf, rows, ws, interpret=kops._interpret()
@@ -716,34 +829,31 @@ class DeviceEngine:
         one = self._one_factor_syrk(Lp, Wp)
         one_g = self._one_factor_syrk_guarded(Lp, Wp, clamp) if guard else None
 
-        def gather(chunk, pool, lb, gidx, src, lo, hi):
-            pc = jax.lax.dynamic_slice(chunk, (0, lb), (M, r))
+        def gather(chunk, pool, lb, src, hi, pstart, rows, ws):
+            pc = jax.lax.dynamic_slice(chunk, (jnp.zeros_like(lb), lb), (M, r))
             if n_in:
-                vals = pool[:, src]   # (M, n_in) destination-sorted entries
-                C = jnp.concatenate(
-                    [jnp.zeros((M, 1), pool.dtype), jnp.cumsum(vals, axis=1)],
-                    axis=1,
-                )
-                pc = pc - (C[:, hi] - C[:, lo])
+                # (M, n_in) destination-sorted entries
+                pc = pc - segment_sums(pool[:, src], hi)
             ext = jnp.concatenate(
                 [pc, jnp.zeros((M, 1), pc.dtype), jnp.ones((M, 1), pc.dtype)],
                 axis=1,
             )
+            gidx = panel_index(jnp, pstart, rows, ws, Lp, Wp, r)
             return ext[:, gidx].reshape(M * Bp, Lp, Wp)
 
         def pack(fp, u, pool, ppack, upack, off):
             packed = fp.reshape(M, -1)[:, ppack]
             if n_out:
                 pool = jax.lax.dynamic_update_slice(
-                    pool, u.reshape(M, -1)[:, upack], (0, off)
+                    pool, u.reshape(M, -1)[:, upack], (jnp.zeros_like(off), off)
                 )
             return packed, pool
 
         if guard:
 
-            def f(chunk, pool, lb, off, src, lo, hi, gidx, rows, ws,
+            def f(chunk, pool, lb, off, src, hi, pstart, rows, ws,
                   ppack, upack, thr):
-                buf = gather(chunk, pool, lb, gidx, src, lo, hi)
+                buf = gather(chunk, pool, lb, src, hi, pstart, rows, ws)
                 if backend == "pallas":
                     fp, u, st = fused_factor_syrk(
                         buf, jnp.tile(rows, M), jnp.tile(ws, M),
@@ -758,9 +868,9 @@ class DeviceEngine:
 
         else:
 
-            def f(chunk, pool, lb, off, src, lo, hi, gidx, rows, ws,
+            def f(chunk, pool, lb, off, src, hi, pstart, rows, ws,
                   ppack, upack):
-                buf = gather(chunk, pool, lb, gidx, src, lo, hi)
+                buf = gather(chunk, pool, lb, src, hi, pstart, rows, ws)
                 if backend == "pallas":
                     fp, u = fused_factor_syrk(
                         buf, jnp.tile(rows, M), jnp.tile(ws, M),
@@ -793,22 +903,38 @@ class DeviceEngine:
     # extensions and zero pad rows/columns of P keep that junk out of every
     # real row; the trash rows are reset once per level only to keep their
     # values finite.
-    def _invert_diag_fn(self, Bp: int, Wp: int):
-        """Invert a group's stacked triangular diagonal blocks (finalize-time
-        only; the pallas backend routes through the kernels' TRSM)."""
-        backend = self.backend
+    def _invert_diag_fn(self, Bp: int, Lp: int, Wp: int):
+        """Invert a group's stacked triangular diagonal blocks, the top
+        (Wp, Wp) of its (Bp, Lp, Wp) panels (finalize-time only; XLA for
+        either backend): ``L^{-1} = X^T`` for ``X L^T = I``."""
 
-        def f(Ld):
-            eye = jnp.broadcast_to(jnp.eye(Wp, dtype=Ld.dtype), Ld.shape)
-            if backend == "pallas":
-                return jax.vmap(
-                    lambda A, b: kops.trsm_lln(A, b, backend="pallas")
-                )(Ld, eye)
-            return jax.lax.linalg.triangular_solve(
-                Ld, eye, left_side=True, lower=True
-            )
+        def f(P):
+            eye = jnp.eye(Wp, dtype=P.dtype)
+            return jax.vmap(lambda L: trsm_rlt(L, eye).T)(P[:, :Wp, :])
 
-        return self._program(("invert_diag", Bp, Wp), lambda: jax.jit(f))
+        return self._program(("invert_diag", Bp, Lp, Wp), lambda: jax.jit(f))
+
+    def _panels_fn(self, M: int, Bp: int, Lp: int, Wp: int, r: int):
+        """Gather one group's stacked padded factored panels P from the
+        concatenated device factor (finalize-time; see
+        ``DevicePanelStore._materialize_panels``).  ``base`` (the group's
+        offset in the factor) and ``total`` (the zero/one pair's offset)
+        are traced.  With M matrices the M factors stack into one
+        (M*Bp, Lp, Wp) batch and the solve targets shift by mi*(n+1)."""
+
+        def f(factor_ext, pstart, rows, ws, cols, tails, base, total,
+              stride):
+            gidx = panel_index(jnp, pstart, rows, ws, Lp, Wp, r)
+            sg = jnp.where(gidx < r, gidx + base, gidx - r + total)
+            if M == 1:
+                return factor_ext[sg], cols, tails
+            shift = (jnp.arange(M, dtype=cols.dtype) * stride)[:, None, None]
+            return (factor_ext[:, sg].reshape(M * Bp, Lp, Wp),
+                    (cols[None] + shift).reshape(M * Bp, -1),
+                    (tails[None] + shift).reshape(M * Bp, -1))
+
+        return self._program(("panels", M, Bp, Lp, Wp, r),
+                             lambda: jax.jit(f))
 
     def _solve_fwd_fn(self, shapes: tuple, nrhs: int, ntrash: int):
         """Forward substitution for one level: per group one batched
@@ -818,11 +944,13 @@ class DeviceEngine:
         def f(y, trash, Ps, Dinvs, colss, tailss):
             for P, Dinv, cols, tails in zip(Ps, Dinvs, colss, tailss):
                 Lp, Wp = P.shape[1], P.shape[2]
-                z = Dinv @ y[cols]                  # (Bp, Wp, nrhs)
-                y = y.at[cols.reshape(-1)].set(z.reshape(-1, z.shape[2]))
+                z = _mm(Dinv, y[cols])              # (Bp, Wp, nrhs)
+                y = scatter_rows(y, cols.reshape(-1),
+                                 z.reshape(-1, z.shape[2]))
                 if Lp > Wp:
-                    u = P[:, Wp:, :] @ z            # (Bp, Lp-Wp, nrhs)
-                    y = y.at[tails.reshape(-1)].add(-u.reshape(-1, u.shape[2]))
+                    u = _mm(P[:, Wp:, :], z)        # (Bp, Lp-Wp, nrhs)
+                    y = scatter_rows(y, tails.reshape(-1),
+                                     -u.reshape(-1, u.shape[2]), add=True)
             return y.at[trash].set(0.0)             # reset the trash row(s)
 
         return self._program(
@@ -838,9 +966,10 @@ class DeviceEngine:
                 Lp, Wp = P.shape[1], P.shape[2]
                 r = y[cols]                         # (Bp, Wp, nrhs)
                 if Lp > Wp:
-                    r = r - P[:, Wp:, :].transpose(0, 2, 1) @ y[tails]
-                z = Dinv.transpose(0, 2, 1) @ r     # (L^T)^{-1} = (L^{-1})^T
-                y = y.at[cols.reshape(-1)].set(z.reshape(-1, z.shape[2]))
+                    r = r - _mm(P[:, Wp:, :].transpose(0, 2, 1), y[tails])
+                z = _mm(Dinv.transpose(0, 2, 1), r)  # (L^T)^{-1} = (L^{-1})^T
+                y = scatter_rows(y, cols.reshape(-1),
+                                 z.reshape(-1, z.shape[2]))
             return y.at[trash].set(0.0)
 
         return self._program(
@@ -855,7 +984,7 @@ class DeviceEngine:
         (trash rows map to an arbitrary source; they are zeroed)."""
 
         def f(b, iperm, trash):
-            y = b[iperm]
+            y = b[iperm].astype(self.dtype)
             return y.at[trash].set(0.0)
 
         return self._program(("stage_rhs", n, nt), lambda: jax.jit(f))
@@ -884,7 +1013,7 @@ class DeviceEngine:
     def stage(self, P: np.ndarray, w: int) -> _Handle:
         rows = P.shape[0]
         Lp, Wp = bucket_shape(rows, w)
-        buf = np.zeros((Lp, Wp), dtype=P.dtype)
+        buf = np.zeros((Lp, Wp), dtype=self.dtype)
         self._pack_panel(buf, P, w, Wp)
         dev = jax.device_put(buf)
         self.stats["transfers_in"] += 1
@@ -893,12 +1022,10 @@ class DeviceEngine:
 
     def factor(self, h: _Handle) -> None:
         self.stats["device_calls"] += 1
-        if self.fused and self.backend == "pallas":
+        if self.fused:
             h.dev, h._u = self._factor_syrk_fn(h.Lp, h.Wp)(
                 h.dev, np.int32(h.rows), np.int32(h.w)
             )
-        elif self.fused:
-            h.dev, h._u = self._factor_syrk_fn(h.Lp, h.Wp)(h.dev)
         else:
             h.dev = self._factor_fn(h.Lp, h.Wp)(h.dev)
             h._u = None
@@ -955,7 +1082,7 @@ class DeviceEngine:
             raise ValueError(f"stage_batch: mixed buckets {sorted(shapes)}")
         (Lp, Wp), = shapes
         Bp = _bucket_batch(B)
-        buf = np.zeros((Bp, Lp, Wp), dtype=np.float64)
+        buf = np.zeros((Bp, Lp, Wp), dtype=self.dtype)
         for i, (P, w) in enumerate(zip(Ps, ws)):
             self._pack_panel(buf[i], P, w, Wp)
         if Bp > B:  # identity pad lanes: chol(I) = I, zero tails, zero updates
@@ -970,14 +1097,11 @@ class DeviceEngine:
         self.stats["device_calls"] += 1
         Bp = hb.dev.shape[0]
         fn = self._batch_factor_syrk_fn(Bp, hb.Lp, hb.Wp)
-        if self.backend == "pallas":
-            rows = np.zeros(Bp, np.int32)
-            ws = np.zeros(Bp, np.int32)
-            rows[:hb.B] = hb.rows
-            ws[:hb.B] = hb.ws
-            hb.dev, hb._u = fn(hb.dev, rows, ws)
-        else:
-            hb.dev, hb._u = fn(hb.dev)
+        rows = np.zeros(Bp, np.int32)
+        ws = np.zeros(Bp, np.int32)
+        rows[:hb.B] = hb.rows
+        ws[:hb.B] = hb.ws
+        hb.dev, hb._u = fn(hb.dev, rows, ws)
 
     def read_panels_batch(self, hb: _BatchHandle) -> list:
         dv = jax.device_get(hb.dev)  # one bulk transfer for the whole batch
@@ -1035,22 +1159,19 @@ class DeviceEngine:
         """Build one group's stacked padded panel buffer on the device (see
         repro.core.device_store._DevGroup for ``g``).  Zero transfers."""
         self.stats["device_calls"] += 1
-        Bp, Lp, Wp = g.gidx.shape
         fn = self._gather_group_fn(
-            Bp, Lp, Wp, int(g.cells.shape[0]), int(g.src.shape[0])
+            g.Bp, g.Lp, g.Wp, int(g.cells.shape[0]), int(g.src.shape[0])
         )
-        return fn(storage0, pool, g.cells, g.src, g.lo, g.hi, g.gidx)
+        return fn(storage0, pool, g.cells, g.src, g.hi, g.pstart, g.rows,
+                  g.ws)
 
-    def factor_group(self, buf, rows=None, ws=None):
+    def factor_group(self, buf, rows, ws):
         """One batched fused POTRF+TRSM+SYRK dispatch over a stacked buffer.
         ``rows``/``ws`` are the group's true per-lane extents (pad lanes 0),
-        required by the pallas masked kernel and ignored by the xla path."""
+        required by the masked kernel and ignored by the XLA program."""
         self.stats["device_calls"] += 1
         Bp, Lp, Wp = buf.shape
-        fn = self._batch_factor_syrk_fn(Bp, Lp, Wp)
-        if self.backend == "pallas":
-            return fn(buf, rows, ws)
-        return fn(buf)
+        return self._batch_factor_syrk_fn(Bp, Lp, Wp)(buf, rows, ws)
 
     def pack_group(self, fp, u, pool, g):
         """Pack one group's factored panels and update entries (in-place pool
@@ -1063,42 +1184,51 @@ class DeviceEngine:
         )
         return fn(fp, u, pool, g.ppack, g.upack, g.off)
 
-    def _group_tiers(self) -> list:
-        """Fallback chain for fused-group dispatch: the primary backend,
-        then xla (if it was not the primary), then a host re-factor of the
-        failing group.  Bounded — each tier runs at most once per group."""
-        tiers = [self.backend]
-        if self.backend != "xla":
+    def _group_tiers(self, first: str) -> list:
+        """Fallback chain for fused-group dispatch: the program the shape
+        routes to, then xla (if it was not the first), then a host
+        re-factor of the failing group.  Bounded — each tier runs at most
+        once per group."""
+        tiers = [first]
+        if first != "xla":
             tiers.append("xla")
         tiers.append("host")
         return tiers
+
+    def _group_program(self, many: bool, chunk, g, guard: bool,
+                       clamp: bool, backend: str):
+        """The jitted program of one fused group dispatch on ``backend``."""
+        r, n_in, n_out = g.r, int(g.src.shape[0]), int(g.upack.shape[0])
+        if many:
+            return self._fused_group_many_fn(
+                int(chunk.shape[0]), g.Bp, g.Lp, g.Wp, int(chunk.shape[1]),
+                r, n_in, n_out, guard=guard, clamp=clamp, backend=backend)
+        return self._fused_group_fn(
+            g.Bp, g.Lp, g.Wp, int(chunk.shape[0]), r, n_in, n_out,
+            guard=guard, clamp=clamp, backend=backend)
 
     def _run_group_chain(self, many: bool, chunk, pool, g, lvl: int,
                          guard: bool, thr: float, clamp: bool):
         """Dispatch one fused group through the fallback chain.
 
-        The first tier runs the fault-injection ``on_dispatch`` hook (so an
-        injected dispatch failure exercises the chain); a tier that raises
-        is logged as a ``fallback:<next tier>`` event and counted in
-        ``self.fallbacks``.  Re-dispatching the same donated pool buffer is
+        The first tier is the program ``uses_kernel`` picks from the bucket
+        shape, and runs the fault-injection ``on_dispatch`` hook.  Only a
+        runtime dispatch fault (``DispatchFault``) moves on to the
+        next tier, logged as a ``fallback:<next tier>`` event and counted
+        in ``self.fallbacks``; a lowering, compile or out-of-memory error
+        propagates, since retrying it elsewhere would hide a program the
+        device cannot run.  Re-dispatching the same donated pool buffer is
         safe on backends that ignore donation (CPU); on hardware that
         honours it the host tier re-derives everything from host copies.
         If every tier fails, the first error propagates."""
-        Bp, Lp, Wp = g.gidx.shape
-        if many:
-            key_args = (int(chunk.shape[0]), Bp, Lp, Wp, int(chunk.shape[1]),
-                        int(g.ppack.shape[0]), int(g.src.shape[0]),
-                        int(g.upack.shape[0]))
-            build = self._fused_group_many_fn
-        else:
-            key_args = (Bp, Lp, Wp, int(chunk.shape[0]),
-                        int(g.ppack.shape[0]), int(g.src.shape[0]),
-                        int(g.upack.shape[0]))
-            build = self._fused_group_fn
-        args = (chunk, pool, g.lb, g.off, g.src, g.lo, g.hi, g.gidx,
+        first = ("pallas" if self.uses_kernel(g.Lp, g.Wp, guard=guard)
+                 else "xla")
+        args = (chunk, pool, g.lb, g.off, g.src, g.hi, g.pstart,
                 g.rows, g.ws, g.ppack, g.upack)
+        if guard:
+            args += (thr,)
         first_err = None
-        for i, be in enumerate(self._group_tiers()):
+        for i, be in enumerate(self._group_tiers(first)):
             if i > 0:
                 self.fallbacks[be] = self.fallbacks.get(be, 0) + 1
                 self._event(f"fallback:{be}", lvl)
@@ -1111,10 +1241,10 @@ class DeviceEngine:
                         clamp=clamp
                     )
                 else:
-                    fn = build(*key_args, guard=guard, clamp=clamp,
-                               backend=be)
-                    out = fn(*args, thr) if guard else fn(*args)
-            except Exception as e:  # noqa: BLE001 — any tier failure degrades
+                    out = self._group_program(many, chunk, g, guard, clamp,
+                                              be)(*args)
+                    self.group_programs[be] += 1
+            except DispatchFault as e:
                 if first_err is None:
                     first_err = e
                 continue
@@ -1158,15 +1288,17 @@ class DeviceEngine:
         """Last-resort tier: re-derive one group's gather + factor + pack in
         numpy from host copies of the operands.  Runs only when every device
         tier raised, so the transfers it needs are counted honestly."""
-        Bp, Lp, Wp = g.gidx.shape
+        Bp, Lp, Wp = g.Bp, g.Lp, g.Wp
         mp = Lp - Wp
         ch = np.asarray(jax.device_get(chunk), dtype=np.float64)
         # device_get can hand back a read-only view of the device buffer;
         # the pool is written below (update segments), so take a real copy
         po = np.array(jax.device_get(pool), dtype=np.float64)
         idx = {k: np.asarray(jax.device_get(getattr(g, k)))
-               for k in ("src", "lo", "hi", "gidx", "rows", "ws",
+               for k in ("src", "hi", "pstart", "rows", "ws",
                          "ppack", "upack")}
+        idx["lo"] = np.concatenate([[0], idx["hi"][:-1]]).astype(
+            idx["hi"].dtype)
         self.stats["transfers_out"] += 1
         self.stats["bytes_out"] += ch.nbytes + po.nbytes
         lb, off = int(g.lb), int(g.off)
@@ -1184,7 +1316,8 @@ class DeviceEngine:
                 C = np.concatenate([[0.0], np.cumsum(vals)])
                 pc -= C[idx["hi"]] - C[idx["lo"]]
             ext = np.concatenate([pc, [0.0], [1.0]])
-            buf = ext[idx["gidx"]]                   # (Bp, Lp, Wp)
+            buf = ext[panel_index(np, idx["pstart"], idx["rows"], idx["ws"],
+                                  Lp, Wp, r)]        # (Bp, Lp, Wp)
             fp = np.zeros_like(buf)
             u = np.zeros((Bp, mp, mp))
             for b in range(Bp):
@@ -1200,6 +1333,8 @@ class DeviceEngine:
                     u.reshape(-1)[idx["upack"]]
         self.stats["transfers_in"] += 1
         self.stats["bytes_in"] += packed.nbytes + po.nbytes
+        packed, po = packed.astype(self.dtype), po.astype(self.dtype)
+        sts = sts.astype(self.dtype)
         if many:
             out_packed = jax.device_put(packed)
             out_pool = jax.device_put(po)
@@ -1215,8 +1350,7 @@ class DeviceEngine:
     def invert_diag(self, P):
         """Invert one group's stacked diagonal blocks (finalize-time)."""
         self.stats["device_calls"] += 1
-        Bp, Lp, Wp = P.shape
-        return self._invert_diag_fn(Bp, Wp)(P[:, :Wp, :])
+        return self._invert_diag_fn(*P.shape)(P)
 
     def solve_fwd_level(self, y, trash, Ps, Dinvs, colss, tailss):
         """One forward-substitution level against the device-resident RHS."""

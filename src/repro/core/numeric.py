@@ -49,7 +49,7 @@ device and the triangular diagonal blocks pre-inverted into batched GEMMs.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 import numpy as np
 import scipy.linalg as sla
@@ -152,11 +152,14 @@ class CholeskyFactor:
     # accelerator for transfer-free solves
     store: object | None = None
     dstore: object | None = None
-    # breakdown-safety extras (guarded factorizations only): the reduced
-    # per-factorization GuardReport, and the original matrix solves refine
-    # against when the factor carries recorded perturbations or a shift
+    # breakdown-safety extras: the reduced per-factorization GuardReport
+    # (guarded factorizations only), and the original matrix solves refine
+    # against whenever the factor is inexact for it — recorded
+    # perturbations, a shift, or a float32 device factor
     guard_report: object | None = None
     guard_A: object | None = None
+    # relative-residual trajectory of every refined solve on this factor
+    ir_history: list = field(default_factory=list)
 
     def L_dense(self) -> np.ndarray:
         """Assemble the full dense L (for small-n validation only)."""
@@ -196,15 +199,13 @@ class CholeskyFactor:
         engine   device backend only: DeviceEngine to stage with when no
                  device-resident factor exists yet (default: a fresh one).
         refine   run residual-driven refinement against the original matrix
-                 (guarded factorizations only).  Default ``None`` auto-enables
-                 it when this factor carries recorded perturbations or a
-                 diagonal shift, so perturbed factors still solve the
-                 *original* system to full precision.
+                 ``guard_A``.  Default ``None`` enables it whenever the
+                 factor records one — recorded perturbations, a diagonal
+                 shift, or a float32 device factor — so such factors still
+                 solve the *original* system to float64 precision.
         """
         if refine is None:
-            refine = (self.guard_report is not None
-                      and self.guard_report.needs_refine
-                      and self.guard_A is not None)
+            refine = self.guard_A is not None
         if refine:
             if self.guard_A is None:
                 raise ValueError(
@@ -214,6 +215,7 @@ class CholeskyFactor:
             from repro.core.refine import refine_solve
             x, hist = refine_solve(self, self.guard_A, b,
                                    backend=backend, engine=engine)
+            self.ir_history.append(hist)
             if self.guard_report is not None:
                 self.guard_report.ir_history.append(hist)
             return x
@@ -679,7 +681,9 @@ class BatchCholeskyFactor:
     stats: dict | None = None
     dstore: object | None = None
     guard_reports: list | None = None  # per-matrix GuardReport (guarded runs)
-    guard_As: list | None = None       # per-matrix original A (perturb mode)
+    # per-matrix original A where the factor is inexact for it (perturbed,
+    # or every matrix of a float32 device factor); None entries are exact
+    guard_As: list | None = None
     _factors: list | None = None
 
     def factor(self, i: int) -> CholeskyFactor:
@@ -702,10 +706,24 @@ class BatchCholeskyFactor:
         """Solve A_i x_i = b_i for all M systems at once: ``b`` is
         (M, n) or (M, n, nrhs) — every substitution level is ONE dispatch
         covering all matrices.  A resident (jax) ``b`` stays resident:
-        zero transfers, resident result."""
+        zero transfers, resident result — unless the factor is float32
+        (``guard_As`` set for every matrix), when block refinement against
+        the original matrices runs on the host and returns float64."""
         from repro.core.device_store import device_solve
 
-        return device_solve(self.dstore, b)
+        if self.guard_As is None or any(A is None for A in self.guard_As):
+            return device_solve(self.dstore, b)
+        from repro.core.refine import block_refine
+
+        B = np.asarray(b, dtype=np.float64)
+        squeeze = B.ndim == 2
+        if squeeze:
+            B = B[..., None]
+        X, _res, _hist = block_refine(
+            lambda X: np.stack([A @ x for A, x in zip(self.guard_As, X)]),
+            lambda R: device_solve(self.dstore, R), B,
+        )
+        return X[..., 0] if squeeze else X
 
 
 def factorize_levels_device_many(

@@ -26,6 +26,8 @@ from __future__ import annotations
 import numpy as np
 import scipy.sparse as sp
 
+from repro.core.engines import DispatchFault
+
 __all__ = [
     "InjectedDispatchError",
     "FaultPlan",
@@ -35,9 +37,9 @@ __all__ = [
 ]
 
 
-class InjectedDispatchError(RuntimeError):
-    """Raised by FaultPlan.on_dispatch to simulate a failed device dispatch
-    (driver fault, OOM, compiler miscompile caught at launch)."""
+class InjectedDispatchError(DispatchFault):
+    """Raised by FaultPlan.on_dispatch to simulate a runtime dispatch fault,
+    which the engine's fallback chain absorbs (a ``DispatchFault``)."""
 
 
 class FaultPlan:
@@ -77,7 +79,7 @@ class FaultPlan:
         self.n_put += 1
         if self.corrupt_upload is not None and self.n_put == self.corrupt_upload:
             self.fired.append(("corrupt_upload", self.n_put))
-            return nan_segment(np.array(x, dtype=np.float64, copy=True))
+            return nan_segment(np.array(x, copy=True))
         return x
 
     def on_dispatch(self, engine, lvl: int) -> None:

@@ -37,28 +37,76 @@ directory for the tiling/masking scheme and the 128-alignment argument.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across the supported range
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams", None
-)
+_HI = jax.lax.Precision.HIGHEST
+#: block-index zero for the index maps: a Python 0 would become an int64
+#: constant under jax_enable_x64, which Mosaic cannot lower
+_I0 = np.int32(0)
+
+#: scoped-VMEM limit the kernel is compiled under (``vmem_limit_bytes``).
+#: v5e has 128 MiB of VMEM per core; the compiler's default scoped limit is
+#: 16 MiB.  ``fits_vmem`` routes buckets over this bound to the XLA group
+#: program before anything is dispatched.
+VMEM_LIMIT = 16 * 2 ** 20
 
 
 def syrk_tile(mp: int, cap: int = 128) -> int:
-    """SYRK column-tile width for a bucket tail of ``mp`` rows: the largest
-    power of two <= ``cap`` dividing ``mp`` (tiles must tile the output
-    exactly).  Falls back to one full-width tile when ``mp`` is odd — no
-    tail skipping, but no ragged tile either."""
+    """SYRK column-tile width for a bucket tail of ``mp`` rows.  The u
+    out-block is ``(1, mp, tu)``, and the TPU lowering needs its last dim
+    to be a multiple of 128 or the whole dim: ``cap`` when it divides
+    ``mp``, else one full-width tile (no tail skipping, no ragged tile)."""
     if mp <= 0:
         return 1
-    tu = math.gcd(mp, cap)
-    return mp if tu < 8 and tu != mp else tu
+    return cap if mp % cap == 0 else mp
+
+
+def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of a (rows, cols) value: (8, 128) tiles."""
+    return -(-rows // 8) * 8 * (-(-cols // 128) * 128) * itemsize
+
+
+def _live_u(Wp: int) -> int:
+    """u blocks' worth of SYRK-step values the compiler keeps live, by
+    panel width (fitted; kernels/DESIGN.md "Known limits")."""
+    return 2 if Wp <= 64 else 6 if Wp <= 128 else 7
+
+
+def bucket_vmem(Lp: int, Wp: int, *, itemsize: int = 4,
+                guard: bool = False, nb: int = 128) -> dict:
+    """Scoped-VMEM estimate of one grid step for bucket ``(Lp, Wp)``, in
+    (8, 128) tiles: the double-buffered in/out blocks and the ``(Lp, Wp)``
+    scratch of ``fused_factor_syrk``, plus the values the body keeps live.
+    Those are four panels (the panel carried through the column loop and
+    its masked copies), one more when slab updates rebuild the panel
+    (``Wp > nb``), one more for the guard's nonfinite scan, and
+    ``_live_u(Wp)`` u blocks for the SYRK tile.  Fitted to the smallest
+    scoped limit the v5e compiler accepts, bisected per bucket: the table
+    in kernels/DESIGN.md "Known limits" lists each reading beside this
+    estimate, which is above it on every one."""
+    mp = Lp - Wp
+    tu = syrk_tile(mp) if mp else 0
+    panel = _tile_bytes(Lp, Wp, itemsize)  # p_ref / fp_ref block, scratch
+    blk_u = _tile_bytes(mp, tu, itemsize) if mp else 0  # u block (1, mp, tu)
+    blk_st = _tile_bytes(1, STATUS_WIDTH, itemsize) if guard else 0
+    blocks = 2 * (2 * panel + blk_u + blk_st) + panel
+    internal = ((4 + (Wp > nb) + guard) * panel + _live_u(Wp) * blk_u)
+    return {"Lp": Lp, "Wp": Wp, "mp": mp, "tu": tu,
+            "block_in": panel, "block_fp": panel, "block_u": blk_u,
+            "scratch": panel, "internal": internal,
+            "vmem_bytes": blocks + internal}
+
+
+def fits_vmem(Lp: int, Wp: int, itemsize: int, *, guard: bool = False) -> bool:
+    """True when bucket ``(Lp, Wp)`` fits the kernel's scoped VMEM limit;
+    the engine sends larger buckets to the XLA group program instead."""
+    return bucket_vmem(Lp, Wp, itemsize=itemsize,
+                       guard=guard)["vmem_bytes"] <= VMEM_LIMIT
 
 
 #: status-lane row layout (one (1, 128) row per lane, panels.dtype):
@@ -81,14 +129,17 @@ def _fused_kernel(rows_ref, ws_ref, meta_ref, p_ref, fp_ref, u_ref, st_ref,
 
     ri = jax.lax.broadcasted_iota(jnp.int32, (Lp, 1), 0)
     ci = jax.lax.broadcasted_iota(jnp.int32, (1, Wp), 1)
+    dt = p_ref.dtype
+    # dtype-typed constants: under jax_enable_x64 a where() over two Python
+    # floats is float64, which Mosaic cannot lower
+    zero, one = jnp.zeros((), dt), jnp.ones((), dt)
     if guard:
-        # perturbation threshold rides in as the float32 bit pattern of an
-        # int32 scalar-prefetch arg: traced, so per-matrix thresholds never
-        # recompile.  thr == 0 means detect-only (never clamps).
-        thr = jax.lax.bitcast_convert_type(
-            meta_ref[0], jnp.float32
-        ).astype(p_ref.dtype)
+        # perturbation threshold rides in as a float32 scalar-prefetch arg:
+        # traced, so per-matrix thresholds never recompile.  thr == 0 means
+        # detect-only (never clamps).
+        thr = meta_ref[0].astype(dt)
         li = jax.lax.broadcasted_iota(jnp.int32, (1, STATUS_WIDTH), 1)
+        tiny = jnp.asarray(jnp.finfo(dt).tiny, dt)
 
     @pl.when(tj == 0)
     def _factor():
@@ -98,13 +149,11 @@ def _fused_kernel(rows_ref, ws_ref, meta_ref, p_ref, fp_ref, u_ref, st_ref,
         # scalar-prefetched extents — pad cells may hold anything.
         a = p_ref[0]
         keep = ((ri < w) & (ci < w)) | ((ri >= Wp) & (ri < Wp + m) & (ci < w))
-        a = jnp.where(keep, a, 0.0)
-        a = jnp.where((ri == ci) & (ri >= w), 1.0, a)
+        a = jnp.where(keep, a, zero)
+        a = jnp.where((ri == ci) & (ri >= w), one, a)
         acc_ref[...] = a
         if guard:
-            st_ref[...] = jnp.where(
-                li == 0, jnp.inf, 0.0
-            ).astype(st_ref.dtype)
+            st_ref[0] = jnp.where(li == 0, jnp.asarray(jnp.inf, dt), zero)
 
         # 2. blocked POTRF+TRSM over nb-column slabs.  Identity-extension
         # columns never receive updates (their rows of real columns are
@@ -154,7 +203,7 @@ def _fused_kernel(rows_ref, ws_ref, meta_ref, p_ref, fp_ref, u_ref, st_ref,
                             jnp.where(ri > k, jnp.abs(colk), 0.0)
                         )
                         gfloor = theta * theta * (
-                            GFLOOR_MULT / jnp.maximum(thr, 1e-300)
+                            GFLOOR_MULT / jnp.maximum(thr, tiny)
                         )
                         cl = real & (thr > 0) & (
                             jnp.logical_not(d2 >= thr)
@@ -164,9 +213,9 @@ def _fused_kernel(rows_ref, ws_ref, meta_ref, p_ref, fp_ref, u_ref, st_ref,
                             jnp.maximum(thr, jnp.abs(d2)), gfloor
                         )
                         d2c = jnp.where(jnp.isfinite(d2c), d2c, thr)
-                        ncl = ncl + jnp.where(cl, 1.0, 0.0).astype(ncl.dtype)
+                        ncl = ncl + jnp.where(cl, one, zero)
                         dmag = jnp.where(jnp.isfinite(d2), d2c - d2, d2c)
-                        mag = mag + jnp.where(cl, dmag, 0.0).astype(mag.dtype)
+                        mag = mag + jnp.where(cl, dmag, zero)
                         d2 = jnp.where(cl, d2c, d2)
                     dk = jnp.sqrt(d2)
                     colk = colk / dk
@@ -176,12 +225,15 @@ def _fused_kernel(rows_ref, ws_ref, meta_ref, p_ref, fp_ref, u_ref, st_ref,
                     # vector is `below` at the diagonal-block rows
                     trail = (ci > k) & (ci < hi)
                     bd = jnp.where(trail, below[:Wp].reshape(1, Wp), 0.0)
-                    a = a - jnp.dot(below, bd,
-                                    preferred_element_type=a.dtype)
+                    a = a - jnp.dot(below, bd, precision=_HI,
+                                    preferred_element_type=dt)
                     return jnp.where(ci == k, lcol, a), mind2, ncl, mag
 
+                # int32 loop bounds: under jax_enable_x64 Python-int bounds
+                # give an int64 index, which Mosaic cannot lower
+                lo_j, hi_j = jnp.int32(0), jnp.int32(hi - k0)
                 if guard:
-                    st = st_ref[...]
+                    st = st_ref[0]
                     mind2 = jnp.sum(jnp.where(li == 0, st, 0.0))
                     ncl = jnp.sum(jnp.where(li == 1, st, 0.0))
                     mag = jnp.sum(jnp.where(li == 3, st, 0.0))
@@ -190,9 +242,9 @@ def _fused_kernel(rows_ref, ws_ref, meta_ref, p_ref, fp_ref, u_ref, st_ref,
                         return col_body(j, *carry)
 
                     a, mind2, ncl, mag = jax.lax.fori_loop(
-                        0, hi - k0, col_step, (a, mind2, ncl, mag)
+                        lo_j, hi_j, col_step, (a, mind2, ncl, mag)
                     )
-                    st_ref[...] = jnp.where(
+                    st_ref[0] = jnp.where(
                         li == 0, mind2,
                         jnp.where(li == 1, ncl, jnp.where(li == 3, mag, st)),
                     )
@@ -201,13 +253,13 @@ def _fused_kernel(rows_ref, ws_ref, meta_ref, p_ref, fp_ref, u_ref, st_ref,
                     def col_step(j, a):
                         return col_body(j, a, None, None, None)[0]
 
-                    a = jax.lax.fori_loop(0, hi - k0, col_step, a)
+                    a = jax.lax.fori_loop(lo_j, hi_j, col_step, a)
                 if hi < Wp:
                     # one MXU matmul pushes the slab into trailing columns
                     slabL = a[:, k0:hi]                        # (Lp, nb)
                     down = slabL[hi:Wp, :]                     # (Wp-hi, nb)
-                    upd = jnp.dot(slabL, down.T,
-                                  preferred_element_type=a.dtype)
+                    upd = jnp.dot(slabL, down.T, precision=_HI,
+                                  preferred_element_type=dt)
                     a = jnp.concatenate(
                         [a[:, :hi], a[:, hi:] - upd], axis=1
                     )
@@ -215,11 +267,11 @@ def _fused_kernel(rows_ref, ws_ref, meta_ref, p_ref, fp_ref, u_ref, st_ref,
 
         fp_ref[0] = acc_ref[...]
         if guard:
-            st = st_ref[...]
-            bad = jnp.any(jnp.logical_not(jnp.isfinite(acc_ref[...])))
-            st_ref[...] = jnp.where(
-                li == 2, jnp.where(bad, 1.0, 0.0).astype(st.dtype), st
-            )
+            st = st_ref[0]
+            # max over a dt-typed flag: a bool any() reduction is float64
+            # under jax_enable_x64
+            bad = jnp.max(jnp.where(jnp.isfinite(acc_ref[...]), zero, one))
+            st_ref[0] = jnp.where(li == 2, bad, st)
 
     # 3. SYRK column tile tj of U = tril(T T^T), T the factored tail.
     # Tiles at/after the lane's true tail extent are skipped outright.
@@ -228,13 +280,16 @@ def _fused_kernel(rows_ref, ws_ref, meta_ref, p_ref, fp_ref, u_ref, st_ref,
         @pl.when(tj * tu < m)
         def _syrk_tile():
             tail = acc_ref[Wp:, :]                             # (mp, Wp)
-            blk = jax.lax.dynamic_slice(
-                tail, (tj * tu, jnp.zeros_like(tj)), (tu, Wp)
+            # a ref slice: Mosaic has no lowering for a value dynamic_slice
+            r0 = pl.multiple_of(Wp + tj * tu, 8)
+            blk = acc_ref[pl.ds(r0, tu), :]                    # (tu, Wp)
+            part = jax.lax.dot_general(
+                tail, blk, (((1,), (1,)), ((), ())), precision=_HI,
+                preferred_element_type=dt,
             )
-            part = jnp.dot(tail, blk.T, preferred_element_type=tail.dtype)
             rg = jax.lax.broadcasted_iota(jnp.int32, (mp, 1), 0)
             cg = tj * tu + jax.lax.broadcasted_iota(jnp.int32, (1, tu), 1)
-            u_ref[0] = jnp.where(rg >= cg, part, 0.0)
+            u_ref[0] = jnp.where(rg >= cg, part, zero)
 
         @pl.when(tj * tu >= m)
         def _skip_tile():
@@ -243,7 +298,7 @@ def _fused_kernel(rows_ref, ws_ref, meta_ref, p_ref, fp_ref, u_ref, st_ref,
     @pl.when((tj == 0) & (w == 0))
     def _pad_lane():
         # pad lane (rows = w = 0): identity panel, no factor loop ran
-        fp_ref[0] = jnp.where(ri == ci, 1.0, 0.0).astype(fp_ref.dtype)
+        fp_ref[0] = jnp.where(ri == ci, one, zero)
         acc_ref[...] = fp_ref[0]
 
 
@@ -286,15 +341,19 @@ def fused_factor_syrk(
     ws = ws.astype(jnp.int32)
 
     out_shapes = [jax.ShapeDtypeStruct((Bp, Lp, Wp), panels.dtype)]
-    out_specs = [pl.BlockSpec((1, Lp, Wp), lambda b, tj, *_: (b, 0, 0))]
+    out_specs = [pl.BlockSpec((1, Lp, Wp), lambda b, tj, *_: (b, _I0, _I0))]
     if mp:
         out_shapes.append(jax.ShapeDtypeStruct((Bp, mp, mp), panels.dtype))
-        out_specs.append(pl.BlockSpec((1, mp, tu), lambda b, tj, *_: (b, 0, tj)))
+        out_specs.append(pl.BlockSpec((1, mp, tu), lambda b, tj, *_: (b, _I0, tj)))
     if guard:
+        # (Bp, 1, 128): the block's last two dims equal the array's, as the
+        # TPU lowering requires of a block narrower than (8, 128)
         out_shapes.append(
-            jax.ShapeDtypeStruct((Bp, STATUS_WIDTH), panels.dtype)
+            jax.ShapeDtypeStruct((Bp, 1, STATUS_WIDTH), panels.dtype)
         )
-        out_specs.append(pl.BlockSpec((1, STATUS_WIDTH), lambda b, tj, *_: (b, 0)))
+        out_specs.append(
+            pl.BlockSpec((1, 1, STATUS_WIDTH), lambda b, tj, *_: (b, _I0, _I0))
+        )
 
     body = functools.partial(
         _fused_kernel, Lp=Lp, Wp=Wp, nb=nb, tu=tu, guard=guard
@@ -311,14 +370,15 @@ def fused_factor_syrk(
                  outs[1] if mp else None, None, acc_ref)
 
     kw = {}
-    if not interpret and _CompilerParams is not None:
-        kw["compiler_params"] = _CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
+    if not interpret:
+        kw["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT,
         )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3 if guard else 2,
         grid=(Bp, ntj),
-        in_specs=[pl.BlockSpec((1, Lp, Wp), lambda b, tj, *_: (b, 0, 0))],
+        in_specs=[pl.BlockSpec((1, Lp, Wp), lambda b, tj, *_: (b, _I0, _I0))],
         out_specs=out_specs,
         scratch_shapes=[pltpu.VMEM((Lp, Wp), panels.dtype)],
     )
@@ -330,15 +390,12 @@ def fused_factor_syrk(
         **kw,
     )
     if guard:
-        # SMEM scalars are int32: ship thr as the bit pattern of its f32 value
-        meta = jax.lax.bitcast_convert_type(
-            jnp.asarray(thr, jnp.float32).reshape(1), jnp.int32
-        )
+        meta = jnp.asarray(thr, jnp.float32).reshape(1)
         out = call(rows, ws, meta, panels)
     else:
         out = call(rows, ws, panels)
     fp = out[0]
     u = out[1] if mp else jnp.zeros((Bp, 0, 0), panels.dtype)
     if guard:
-        return fp, u, out[-1][:, :STATUS_COLS]
+        return fp, u, out[-1][:, 0, :STATUS_COLS]
     return fp, u

@@ -9,7 +9,8 @@ and slices the result back.  ``backend`` picks the implementation:
                kernels; also the fast path on this CPU-only container)
 
 Default backend comes from REPRO_KERNEL_BACKEND, else 'pallas' on TPU and
-'xla' elsewhere.  Kernel-vs-oracle equivalence is enforced by the test suite.
+'xla' elsewhere; ``device_dtype`` picks the device value dtype the same way.
+Kernel-vs-oracle equivalence is enforced by the test suite.
 """
 from __future__ import annotations
 
@@ -36,6 +37,13 @@ def default_backend() -> str:
     except RuntimeError:
         pass
     return "xla"
+
+
+def device_dtype():
+    """Value dtype of the device numeric path: float32 on TPU, where Mosaic
+    has no float64 (the float64 answer comes from refinement against ``A``,
+    ``repro.core.refine``); float64 elsewhere."""
+    return jnp.float32 if jax.default_backend() == "tpu" else jnp.float64
 
 
 def _interpret() -> bool:

@@ -34,6 +34,7 @@ from repro.core import cholesky, cholesky_many, counters
 from repro.core.engines import DeviceEngine
 from repro.core.guard import BadMatrixError, BreakdownError
 from repro.core.plan_cache import PlanCache
+from repro.launch.compile_cache import enable_compile_cache
 
 #: a refined solve that cannot push the relative residual below this is
 #: served (best effort) but marks its factor dirty — the factor is evicted
@@ -107,16 +108,17 @@ class CholeskyServer:
     ``max_cache_bytes`` bounds the plan cache (LRU demotion to disk).
     """
 
-    def __init__(self, *, cache_dir=None, backend: str | None = "xla",
+    def __init__(self, *, cache_dir=None, backend: str | None = None,
                  max_batch: int = 256, staging: str | None = None,
                  warm_buckets: tuple | None = None, verify: bool = False,
                  guard: str = "raise", max_cache_bytes: int | None = None):
+        # backend None resolves like the kernel ops: pallas on TPU, xla else
+        self.engine = DeviceEngine(backend=backend)
         if warm_buckets is None:
-            eff = backend if backend is not None else ""
-            warm_buckets = ("fused",) if eff == "pallas" else ("batch",)
+            warm_buckets = (("fused",) if self.engine.backend == "pallas"
+                            else ("batch",))
         self.cache = PlanCache(cache_dir=cache_dir, warm_buckets=warm_buckets,
                                max_bytes=max_cache_bytes)
-        self.engine = DeviceEngine(backend=backend)
         self.max_batch, self.staging = max_batch, staging
         self.guard = guard
         self.factors: dict = {}
@@ -422,7 +424,9 @@ def main():
     ap.add_argument("--many", type=int, default=4,
                     help="matrices per batched factor request")
     ap.add_argument("--nrhs", type=int, default=4)
-    ap.add_argument("--backend", default="xla", choices=["xla", "pallas"])
+    ap.add_argument("--backend", default=None, choices=["xla", "pallas"],
+                    help="device program (default: pallas on TPU, xla "
+                         "elsewhere)")
     ap.add_argument("--guard", default="raise",
                     choices=["off", "raise", "perturb", "shift"],
                     help="breakdown policy for factor requests")
@@ -436,6 +440,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     srv = CholeskyServer(cache_dir=args.cache_dir, backend=args.backend,
                          verify=args.verify, guard=args.guard,
                          max_cache_bytes=args.max_cache_bytes)

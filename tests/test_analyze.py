@@ -321,10 +321,19 @@ def test_traced_factorization_clean(staging):
 # pass 3: kernel static analysis
 # ---------------------------------------------------------------------------
 def test_bucket_vmem_estimate_shape():
-    est = bucket_vmem(256, 128)
+    est = bucket_vmem(256, 128)  # float32, (8, 128)-tiled values
     assert est["mp"] == 128 and est["tu"] == 128
-    assert est["vmem_bytes"] == 2 * (2 * 256 * 128 + 128 * 128) * 8 \
-        + 256 * 128 * 8
+    panel, ublk = 256 * 128 * 4, 128 * 128 * 4
+    blocks = 2 * (2 * panel + ublk) + panel   # double-buffered + scratch
+    assert est["vmem_bytes"] == blocks + 4 * panel + 6 * ublk
+    # a second slab adds a rebuilt panel; the guard adds its scan and
+    # double-buffered (1, 128) status block
+    wide = bucket_vmem(768, 256)
+    panel, ublk = 768 * 256 * 4, 512 * 128 * 4
+    blocks = 2 * (2 * panel + ublk) + panel
+    assert wide["vmem_bytes"] == blocks + 5 * panel + 7 * ublk
+    assert (bucket_vmem(768, 256, guard=True)["vmem_bytes"]
+            == wide["vmem_bytes"] + panel + 2 * 8 * 128 * 4)
 
 
 def test_check_bucket_clean_pow2():

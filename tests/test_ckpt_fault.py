@@ -4,7 +4,6 @@ import json
 import os
 import pathlib
 import signal
-import threading
 import time
 
 import numpy as np
@@ -64,12 +63,15 @@ def test_preemption_and_resume(tmp_path):
     completes the remaining steps with the identical data stream."""
     from repro.launch.train import train
 
-    # fire SIGTERM shortly after training starts
-    killer = threading.Timer(6.0, lambda: os.kill(os.getpid(), signal.SIGTERM))
-    killer.start()
+    # fire SIGTERM once training is under way (after its third step): a
+    # wall-clock timer could land before train() installs its handler and
+    # kill the process outright on a loaded machine
+    def preempt(step, _loss):
+        if step == 2:
+            os.kill(os.getpid(), signal.SIGTERM)
+
     out1 = train("llama3.2-1b", smoke=True, steps=60, batch=4, seq=64,
-                 ckpt_dir=str(tmp_path), ckpt_every=10)
-    killer.cancel()
+                 ckpt_dir=str(tmp_path), ckpt_every=10, on_step=preempt)
     assert out1["preempted"], "expected the run to be preempted"
     assert out1["steps_done"] < 60
     assert latest_step(tmp_path) == out1["steps_done"]

@@ -129,11 +129,16 @@ def test_syrk_tile_divides_tail():
 
 
 def test_fused_bucket_family_pow2():
-    for rows, w in [(1, 1), (9, 1), (20, 8), (130, 100), (700, 300)]:
+    for rows, w in [(1, 1), (9, 1), (20, 8), (130, 100), (700, 300),
+                    (5000, 64)]:
         Lp, Wp = bucket_shape_fused(rows, w)
-        assert Wp >= w and Lp - Wp >= rows - w
-        assert Wp & (Wp - 1) == 0 and Lp & (Lp - 1) == 0
-        assert syrk_tile(Lp - Wp) >= min(8, max(1, Lp - Wp))
+        mp = Lp - Wp
+        assert Wp >= w and mp >= rows - w
+        # width and tail are each a power of two (tail 0 when rows == w)
+        assert Wp & (Wp - 1) == 0 and mp & (mp - 1) == 0
+        assert syrk_tile(mp) >= min(8, max(1, mp))
+        # the u out-block's last dim is 128-aligned or the whole tail
+        assert mp == 0 or syrk_tile(mp) % 128 == 0 or syrk_tile(mp) == mp
 
 
 # ---------------------------------------------------------------------------
