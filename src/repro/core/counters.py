@@ -1,5 +1,7 @@
-"""Build counters for the analysis/plan layers.
+"""The program's instrumentation: build counters, host spans, device scopes.
 
+Build counters
+--------------
 Every expensive symbolic-phase artifact — symbolic analysis, scatter plans,
 level schedules, device index plans, fill plans — bumps a named counter when
 it is *built* (never when a cached copy is returned).  The serving layer's
@@ -10,10 +12,55 @@ tests/test_plan_cache.py and repro.launch.serve).
 Deliberately a process-global registry (not per-object): the point is to
 catch rebuilds wherever they happen, including paths that accidentally drop
 a cached SymbolicFactor and re-analyze from scratch.
+
+Spans and scopes
+----------------
+A user who profiles their own loop with ``jax.profiler.trace(dir)`` finds
+the solver's layers by name in the trace, every name starting with
+``SPAN_PREFIX``.  Host spans (``span``) are ``jax.profiler.TraceAnnotation``
+s: they land on the host thread that ran them, on the clock the device
+planes share, and nest by time (a span's parent is the span enclosing it).
+Off the profiler one costs about a microsecond, so none sits inside a
+per-supernode, per-entry or per-column loop:
+
+    chol.factor    one device-resident factorization (the children below)
+    chol.fill      a cached plan's value fill (``CachedPlan.fill_storage``)
+    chol.stage     host-side staging: the index plan's concatenation and
+                   upload, each level's chunk gather and upload, the solve's
+                   auxiliary upload
+    chol.dispatch  one device program dispatch: one span per increment of
+                   ``DeviceEngine.stats["device_calls"]``
+    chol.readback  one device-to-host transfer, including the wait for the
+                   device: one span per increment of ``stats["transfers_out"]``
+    chol.solve     one level-scheduled device solve
+    chol.refine    one refined solve; its ``chol.solve`` children apply the
+                   factor, the rest (its self time) is host residual work
+
+Device scopes (``scope``) are ``jax.named_scope`` s applied while a program
+is traced, so they cost nothing at run time.  They land in each operation's
+``op_name`` metadata (the trace viewer's ``tf_op``); XLA may fuse operations
+of two neighbouring scopes under one of them.  JAX's persistent compilation
+cache keys a program by its computation without that metadata, so a program
+whose scopes change while its computation and its jitted function's name do
+not is served the executable cached before, with the op_names it had then:
+each scoped program is jitted under a name of its own, which the trace's
+``XLA Modules`` line shows.  The scopes:
+
+    chol.assemble     a group's pool gather, update sums, panel gather, pack
+    chol.segment_ids  the segment ids of the update sums (inside assemble)
+    chol.xla_factor   the XLA factor of a group program (POTRF+TRSM+SYRK)
+    chol.finalize     the device factor's concatenation, its panel gathers
+                      and diagonal inversions
+    chol.level_solve  the substitution levels and the RHS (un)staging
+    chol.stage        the device-side split of the staged index plan
+
+The fused Pallas kernel is a custom call named ``fused_factor_syrk``.
 """
 from __future__ import annotations
 
 from collections import defaultdict
+
+import jax
 
 COUNTS: dict = defaultdict(int)
 
@@ -25,6 +72,9 @@ BUILD_KINDS = (
     "device_plan",        # repro.core.device_store.build_device_plan
     "fill_plan",          # repro.core.plan_cache.build_fill_plan
 )
+
+#: first characters of every span and scope name
+SPAN_PREFIX = "chol."
 
 
 def bump(name: str) -> None:
@@ -41,3 +91,15 @@ def delta(before: dict) -> dict:
     return {
         k: v - before.get(k, 0) for k, v in COUNTS.items() if v != before.get(k, 0)
     }
+
+
+def span(name: str, **meta):
+    """Host span ``chol.<name>`` (a context manager); ``meta`` becomes the
+    event's arguments in the trace."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **meta)
+
+
+def scope(name: str):
+    """Device scope ``chol.<name>`` for the operations traced inside it (a
+    context manager, used in jitted bodies)."""
+    return jax.named_scope(SPAN_PREFIX + name)

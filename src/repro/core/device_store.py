@@ -268,15 +268,16 @@ def build_device_plan(sym: SymbolicFactor, sched: LevelSchedule) -> DeviceGroupP
 
 
 @partial(jax.jit, static_argnums=1)
-def _split(flat, pieces: tuple):
+def _split_plan(flat, pieces: tuple):
     """Slice the one staged index upload into its per-group arrays in ONE
     program (``pieces`` = ((offset, shape), ...), static, so a repeat
     pattern reuses the compiled split): eager slices would compile one tiny
     program per distinct (offset, size)."""
     out = []
-    for off, shape in pieces:
-        n = int(np.prod(shape))
-        out.append(flat[off:off + n].reshape(shape))
+    with counters.scope("stage"):
+        for off, shape in pieces:
+            n = int(np.prod(shape))
+            out.append(flat[off:off + n].reshape(shape))
     return out
 
 
@@ -286,6 +287,14 @@ def _concat_flat(xs):
     leading (matrix) axes of the first (one program per input structure)."""
     lead = xs[0].shape[:-1]
     return jnp.concatenate([x.reshape(lead + (-1,)) for x in xs], axis=-1)
+
+
+@jax.jit
+def _factor_ext(packed, tail):
+    """The device factor: every group's packed panels in dispatch order,
+    then the zero/one pair, along the last axis."""
+    with counters.scope("finalize"):
+        return jnp.concatenate(packed + [tail], axis=-1)
 
 
 def device_plan(sym: SymbolicFactor, sched: LevelSchedule) -> DeviceGroupPlan:
@@ -393,6 +402,34 @@ class DevicePanelStore:
                 "path gathers from the full staged storage)"
             )
         self.staging = staging
+        self.factor_ext = None
+        self.storage0 = None
+        self._packed: list = []
+        self._solve_ready = False
+        self._chunks: list = []
+        self._host_storage = None
+        # resident solve-layout index buffers, uploaded lazily at
+        # ensure_solve_ready (factor-only usage never pays for them)
+        self.trash = None
+        self._iperm = None
+        self._operm = None
+        self.dtype = np.dtype(getattr(eng, "dtype", np.float64))
+        with counters.span("stage"):
+            self._stage(host_storage, factored)
+        if self.fused and staging == "async":
+            # keep the raw storage and gather each level's cells lazily at
+            # prefetch time: by then earlier levels' dispatches are already
+            # in flight, so the host-side gather (and the device_put it
+            # feeds) both overlap compute instead of serializing up front
+            self._host_storage = host_storage
+            self._chunks = [None] * len(gp.groups)
+            self.prefetch_level(0)
+
+    def _stage(self, host_storage: np.ndarray, factored: bool) -> None:
+        """Construction's uploads: the index plan, then the factored panels
+        (``factored``), the raw storage (``staging='sync'``) or nothing
+        (``'async'``, which uploads per level)."""
+        eng, gp, dt = self.eng, self.plan, self.dtype
         # one staged upload of every group's index arrays, device-side slicing
         if factored:
             kinds = ("pstart", "rows_arr", "ws_arr", "cols", "tails")
@@ -413,7 +450,7 @@ class DevicePanelStore:
                     pieces.append((pos, a.shape))
                     pos += a.size
         dflat = eng.put(flat)
-        split = iter(_split(dflat, tuple(pieces)))
+        split = iter(_split_plan(dflat, tuple(pieces)))
         empty = jnp.zeros(0, dflat.dtype)
         self.groups: list = []
         for lvl in gp.groups:
@@ -433,18 +470,6 @@ class DevicePanelStore:
                     Bp=g.Bp, Lp=g.Lp, Wp=g.Wp, r=int(g.cells.shape[0]),
                 ))
             self.groups.append(row)
-        self.factor_ext = None
-        self.storage0 = None
-        self._packed: list = []
-        self._solve_ready = False
-        self._chunks: list = []
-        self._host_storage = None
-        # resident solve-layout index buffers, uploaded lazily at
-        # ensure_solve_ready (factor-only usage never pays for them)
-        self.trash = None
-        self._iperm = None
-        self._operm = None
-        dt = self.dtype = np.dtype(getattr(eng, "dtype", np.float64))
         if factored:
             # stage the already-factored panels, packed (one transfer)
             if self.nmat > 1:
@@ -462,23 +487,15 @@ class DevicePanelStore:
         self.pool = jnp.zeros(pool_shape, dtype=dt)
         if not self.fused:
             self.storage0 = eng.put(host_storage.astype(dt))
-            return
-        # fused staging: the raw storage packed in group (= level) order, so
-        # each level's cells are one contiguous chunk and a group's slice is
-        # [lb, lb + r) — the device never gathers through ``cells`` at all
-        lb = gp.level_base
-        nlev = len(gp.groups)
-        if staging == "sync":
+        elif self.staging == "sync":
+            # fused staging: the raw storage packed in group (= level)
+            # order, so each level's cells are one contiguous chunk and a
+            # group's slice is [lb, lb + r) — the device never gathers
+            # through ``cells`` at all
+            lb = gp.level_base
             whole = eng.put(host_storage[..., gp.cells_concat].astype(dt))
-            self._chunks = [whole[..., lb[l]:lb[l + 1]] for l in range(nlev)]
-        else:
-            # keep the raw storage and gather each level's cells lazily at
-            # prefetch time: by then earlier levels' dispatches are already
-            # in flight, so the host-side gather (and the device_put it
-            # feeds) both overlap compute instead of serializing up front
-            self._host_storage = host_storage
-            self._chunks = [None] * nlev
-            self.prefetch_level(0)
+            self._chunks = [whole[..., lb[l]:lb[l + 1]]
+                            for l in range(len(gp.groups))]
 
     def prefetch_level(self, lvl: int) -> None:
         """Gather one level's packed-storage chunk and issue its
@@ -492,9 +509,10 @@ class DevicePanelStore:
         eng = self.eng
         gp = self.plan
         cells = gp.cells_concat[gp.level_base[lvl]:gp.level_base[lvl + 1]]
-        self._chunks[lvl] = eng.put(
-            self._host_storage[..., cells].astype(self.dtype)
-        )
+        with counters.span("stage"):
+            self._chunks[lvl] = eng.put(
+                self._host_storage[..., cells].astype(self.dtype)
+            )
         if hasattr(eng, "_event"):
             eng._event("upload", lvl)
 
@@ -527,12 +545,10 @@ class DevicePanelStore:
         factor the solve programs read (device op, no transfer)."""
         if self.factor_ext is not None:
             return
-        tail = jnp.array([0.0, 1.0], dtype=self.dtype)
+        tail = np.array([0.0, 1.0], dtype=self.dtype)
         if self.nmat > 1:
-            tail = jnp.tile(tail, (self.nmat, 1))
-            self.factor_ext = jnp.concatenate(self._packed + [tail], axis=1)
-        else:
-            self.factor_ext = jnp.concatenate(self._packed + [tail])
+            tail = np.tile(tail, (self.nmat, 1))
+        self.factor_ext = _factor_ext(self._packed, tail)
         self._packed = []
         self.storage0 = None
         self.pool = None
@@ -563,10 +579,11 @@ class DevicePanelStore:
         iperm[(n + 1) * np.arange(M) + n] = 0
         operm = (iperm_nat[None, :] + stride[:, None]).ravel()
         trash = stride + n
-        aux = self.eng.put(np.concatenate([trash, iperm, operm]))
-        self.trash = aux[:M]
-        self._iperm = aux[M:M + M * (n + 1)]
-        self._operm = aux[M + M * (n + 1):]
+        with counters.span("stage"):
+            aux = self.eng.put(np.concatenate([trash, iperm, operm]))
+            self.trash = aux[:M]
+            self._iperm = aux[M:M + M * (n + 1)]
+            self._operm = aux[M + M * (n + 1):]
         self._solve_ready = True
 
     def _materialize_panels(self) -> None:
@@ -667,6 +684,11 @@ def device_solve(dstore: DevicePanelStore, b) -> np.ndarray:
     ``nmat`` > 1, ``b`` is (nmat, n, nrhs) (or (nmat, n)) and all matrices
     solve in the same dispatches.
     """
+    with counters.span("solve"):
+        return _device_solve(dstore, b)
+
+
+def _device_solve(dstore: DevicePanelStore, b):
     dstore.ensure_solve_ready()
     sym, eng, M = dstore.sym, dstore.eng, dstore.nmat
     n = sym.n

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import counters
 from repro.core.guard import GFLOOR_MULT
 from repro.kernels import ops as kops
 from repro.kernels.fused import fits_vmem, fused_factor_syrk
@@ -139,8 +140,9 @@ def segment_sums(vals, hi):
     # (empty cells are skipped).  A binary search, not a cumsum of segment
     # starts: the TPU compiler takes 2-26 s on a cumsum of 1e5-1e7
     # entries, against about 1 s on this search.
-    seg = jnp.searchsorted(hi, jnp.arange(n, dtype=hi.dtype), side="right",
-                           method="scan")
+    with counters.scope("segment_ids"):
+        seg = jnp.searchsorted(hi, jnp.arange(n, dtype=hi.dtype),
+                               side="right", method="scan")
 
     def one(v):
         return jax.ops.segment_sum(v, seg, num_segments=r,
@@ -414,6 +416,17 @@ class DeviceEngine:
         return self.backend == "pallas" and fits_vmem(
             Lp, Wp, self.dtype.itemsize, guard=guard
         )
+
+    def _dispatch(self):
+        """Count one device program dispatch and open its host span."""
+        self.stats["device_calls"] += 1
+        return counters.span("dispatch")
+
+    def _readback(self):
+        """Count one device-to-host transfer and open its host span, which
+        includes the wait for the device."""
+        self.stats["transfers_out"] += 1
+        return counters.span("readback")
 
     def _event(self, tag: str, lvl: int) -> None:
         if (self.events.maxlen is not None
@@ -758,6 +771,7 @@ class DeviceEngine:
         one = self._one_factor_syrk(Lp, Wp)
         one_g = self._one_factor_syrk_guarded(Lp, Wp, clamp) if guard else None
 
+        @counters.scope("assemble")
         def gather(chunk, pool, lb, src, hi, pstart, rows, ws):
             pc = jax.lax.dynamic_slice(chunk, (lb,), (r,))
             if n_in:
@@ -769,6 +783,7 @@ class DeviceEngine:
             # (Bp, Lp, Wp) stacked padded panels
             return ext[panel_index(jnp, pstart, rows, ws, Lp, Wp, r)]
 
+        @counters.scope("assemble")
         def pack(fp, u, pool, ppack, upack, off):
             packed = fp.reshape(-1)[ppack]
             if n_out:
@@ -779,8 +794,8 @@ class DeviceEngine:
 
         if guard:
 
-            def f(chunk, pool, lb, off, src, hi, pstart, rows, ws,
-                  ppack, upack, thr):
+            def fused_group(chunk, pool, lb, off, src, hi, pstart, rows,
+                            ws, ppack, upack, thr):
                 buf = gather(chunk, pool, lb, src, hi, pstart, rows, ws)
                 if backend == "pallas":
                     fp, u, st = fused_factor_syrk(
@@ -788,29 +803,31 @@ class DeviceEngine:
                         guard=True, thr=thr
                     )
                 else:
-                    fp, u, st = jax.vmap(one_g, in_axes=(0, 0, 0, None))(
-                        buf, rows, ws, thr
-                    )
+                    with counters.scope("xla_factor"):
+                        fp, u, st = jax.vmap(one_g, in_axes=(0, 0, 0, None))(
+                            buf, rows, ws, thr
+                        )
                 packed, pool = pack(fp, u, pool, ppack, upack, off)
                 return packed, pool, st
 
         else:
 
-            def f(chunk, pool, lb, off, src, hi, pstart, rows, ws,
-                  ppack, upack):
+            def fused_group(chunk, pool, lb, off, src, hi, pstart, rows,
+                            ws, ppack, upack):
                 buf = gather(chunk, pool, lb, src, hi, pstart, rows, ws)
                 if backend == "pallas":
                     fp, u = fused_factor_syrk(
                         buf, rows, ws, interpret=kops._interpret()
                     )
                 else:
-                    fp, u = jax.vmap(one)(buf)
+                    with counters.scope("xla_factor"):
+                        fp, u = jax.vmap(one)(buf)
                 return pack(fp, u, pool, ppack, upack, off)
 
         return self._program(
             ("fused_group", Bp, Lp, Wp, clen, r, n_in, n_out,
              backend, guard, clamp),
-            lambda: jax.jit(f, donate_argnums=1),
+            lambda: jax.jit(fused_group, donate_argnums=1),
         )
 
     def _fused_group_many_fn(self, M: int, Bp: int, Lp: int, Wp: int,
@@ -829,6 +846,7 @@ class DeviceEngine:
         one = self._one_factor_syrk(Lp, Wp)
         one_g = self._one_factor_syrk_guarded(Lp, Wp, clamp) if guard else None
 
+        @counters.scope("assemble")
         def gather(chunk, pool, lb, src, hi, pstart, rows, ws):
             pc = jax.lax.dynamic_slice(chunk, (jnp.zeros_like(lb), lb), (M, r))
             if n_in:
@@ -841,6 +859,7 @@ class DeviceEngine:
             gidx = panel_index(jnp, pstart, rows, ws, Lp, Wp, r)
             return ext[:, gidx].reshape(M * Bp, Lp, Wp)
 
+        @counters.scope("assemble")
         def pack(fp, u, pool, ppack, upack, off):
             packed = fp.reshape(M, -1)[:, ppack]
             if n_out:
@@ -851,8 +870,8 @@ class DeviceEngine:
 
         if guard:
 
-            def f(chunk, pool, lb, off, src, hi, pstart, rows, ws,
-                  ppack, upack, thr):
+            def fused_group_many(chunk, pool, lb, off, src, hi, pstart,
+                                 rows, ws, ppack, upack, thr):
                 buf = gather(chunk, pool, lb, src, hi, pstart, rows, ws)
                 if backend == "pallas":
                     fp, u, st = fused_factor_syrk(
@@ -860,16 +879,17 @@ class DeviceEngine:
                         interpret=kops._interpret(), guard=True, thr=thr,
                     )
                 else:
-                    fp, u, st = jax.vmap(one_g, in_axes=(0, 0, 0, None))(
-                        buf, jnp.tile(rows, M), jnp.tile(ws, M), thr
-                    )
+                    with counters.scope("xla_factor"):
+                        fp, u, st = jax.vmap(one_g, in_axes=(0, 0, 0, None))(
+                            buf, jnp.tile(rows, M), jnp.tile(ws, M), thr
+                        )
                 packed, pool = pack(fp, u, pool, ppack, upack, off)
                 return packed, pool, st.reshape(M, Bp, -1)
 
         else:
 
-            def f(chunk, pool, lb, off, src, hi, pstart, rows, ws,
-                  ppack, upack):
+            def fused_group_many(chunk, pool, lb, off, src, hi, pstart,
+                                 rows, ws, ppack, upack):
                 buf = gather(chunk, pool, lb, src, hi, pstart, rows, ws)
                 if backend == "pallas":
                     fp, u = fused_factor_syrk(
@@ -877,13 +897,14 @@ class DeviceEngine:
                         interpret=kops._interpret(),
                     )
                 else:
-                    fp, u = jax.vmap(one)(buf)
+                    with counters.scope("xla_factor"):
+                        fp, u = jax.vmap(one)(buf)
                 return pack(fp, u, pool, ppack, upack, off)
 
         return self._program(
             ("fused_group_many", M, Bp, Lp, Wp, clen, r, n_in, n_out,
              backend, guard, clamp),
-            lambda: jax.jit(f, donate_argnums=1),
+            lambda: jax.jit(fused_group_many, donate_argnums=1),
         )
 
     # Solve programs run one WHOLE LEVEL per dispatch: a level's groups are
@@ -908,11 +929,13 @@ class DeviceEngine:
         (Wp, Wp) of its (Bp, Lp, Wp) panels (finalize-time only; XLA for
         either backend): ``L^{-1} = X^T`` for ``X L^T = I``."""
 
-        def f(P):
+        @counters.scope("finalize")
+        def invert_diag(P):
             eye = jnp.eye(Wp, dtype=P.dtype)
             return jax.vmap(lambda L: trsm_rlt(L, eye).T)(P[:, :Wp, :])
 
-        return self._program(("invert_diag", Bp, Lp, Wp), lambda: jax.jit(f))
+        return self._program(("invert_diag", Bp, Lp, Wp),
+                             lambda: jax.jit(invert_diag))
 
     def _panels_fn(self, M: int, Bp: int, Lp: int, Wp: int, r: int):
         """Gather one group's stacked padded factored panels P from the
@@ -922,8 +945,9 @@ class DeviceEngine:
         are traced.  With M matrices the M factors stack into one
         (M*Bp, Lp, Wp) batch and the solve targets shift by mi*(n+1)."""
 
-        def f(factor_ext, pstart, rows, ws, cols, tails, base, total,
-              stride):
+        @counters.scope("finalize")
+        def finalize_panels(factor_ext, pstart, rows, ws, cols, tails, base,
+                            total, stride):
             gidx = panel_index(jnp, pstart, rows, ws, Lp, Wp, r)
             sg = jnp.where(gidx < r, gidx + base, gidx - r + total)
             if M == 1:
@@ -934,14 +958,15 @@ class DeviceEngine:
                     (tails[None] + shift).reshape(M * Bp, -1))
 
         return self._program(("panels", M, Bp, Lp, Wp, r),
-                             lambda: jax.jit(f))
+                             lambda: jax.jit(finalize_panels))
 
     def _solve_fwd_fn(self, shapes: tuple, nrhs: int, ntrash: int):
         """Forward substitution for one level: per group one batched
         Dinv-GEMM for the diagonal blocks + one batched GEMM scatter-add of
         the tails."""
 
-        def f(y, trash, Ps, Dinvs, colss, tailss):
+        @counters.scope("level_solve")
+        def level_solve_fwd(y, trash, Ps, Dinvs, colss, tailss):
             for P, Dinv, cols, tails in zip(Ps, Dinvs, colss, tailss):
                 Lp, Wp = P.shape[1], P.shape[2]
                 z = _mm(Dinv, y[cols])              # (Bp, Wp, nrhs)
@@ -955,13 +980,14 @@ class DeviceEngine:
 
         return self._program(
             ("solve_fwd", shapes, nrhs, ntrash),
-            lambda: jax.jit(f, donate_argnums=0),
+            lambda: jax.jit(level_solve_fwd, donate_argnums=0),
         )
 
     def _solve_bwd_fn(self, shapes: tuple, nrhs: int, ntrash: int):
         """Backward substitution for one level."""
 
-        def f(y, trash, Ps, Dinvs, colss, tailss):
+        @counters.scope("level_solve")
+        def level_solve_bwd(y, trash, Ps, Dinvs, colss, tailss):
             for P, Dinv, cols, tails in zip(Ps, Dinvs, colss, tailss):
                 Lp, Wp = P.shape[1], P.shape[2]
                 r = y[cols]                         # (Bp, Wp, nrhs)
@@ -974,7 +1000,7 @@ class DeviceEngine:
 
         return self._program(
             ("solve_bwd", shapes, nrhs, ntrash),
-            lambda: jax.jit(f, donate_argnums=0),
+            lambda: jax.jit(level_solve_bwd, donate_argnums=0),
         )
 
     def _stage_rhs_fn(self, n: int, nt: int):
@@ -983,20 +1009,24 @@ class DeviceEngine:
         any host round trip — ``iperm`` maps padded row i to its source row
         (trash rows map to an arbitrary source; they are zeroed)."""
 
-        def f(b, iperm, trash):
+        @counters.scope("level_solve")
+        def stage_rhs(b, iperm, trash):
             y = b[iperm].astype(self.dtype)
             return y.at[trash].set(0.0)
 
-        return self._program(("stage_rhs", n, nt), lambda: jax.jit(f))
+        return self._program(("stage_rhs", n, nt),
+                             lambda: jax.jit(stage_rhs))
 
     def _unstage_rhs_fn(self, n: int, nt: int):
         """Inverse of ``_stage_rhs_fn``: read the solution out of the padded
         solve layout back into natural row order, dropping trash rows."""
 
-        def f(y, operm):
+        @counters.scope("level_solve")
+        def unstage_rhs(y, operm):
             return y[operm]
 
-        return self._program(("unstage_rhs", n, nt), lambda: jax.jit(f))
+        return self._program(("unstage_rhs", n, nt),
+                             lambda: jax.jit(unstage_rhs))
 
     # -- engine protocol ----------------------------------------------------
     @staticmethod
@@ -1021,23 +1051,23 @@ class DeviceEngine:
         return _Handle(dev, rows, w, Lp, Wp)
 
     def factor(self, h: _Handle) -> None:
-        self.stats["device_calls"] += 1
-        if self.fused:
-            h.dev, h._u = self._factor_syrk_fn(h.Lp, h.Wp)(
-                h.dev, np.int32(h.rows), np.int32(h.w)
-            )
-        else:
-            h.dev = self._factor_fn(h.Lp, h.Wp)(h.dev)
-            h._u = None
+        with self._dispatch():
+            if self.fused:
+                h.dev, h._u = self._factor_syrk_fn(h.Lp, h.Wp)(
+                    h.dev, np.int32(h.rows), np.int32(h.w)
+                )
+            else:
+                h.dev = self._factor_fn(h.Lp, h.Wp)(h.dev)
+                h._u = None
 
     def read_panel(self, h: _Handle) -> np.ndarray:
         out = np.empty((h.rows, h.w), dtype=np.float64)
-        dv = np.asarray(h.dev)  # synchronous transfer back (the sequential
-        # path; the device-resident path instead overlaps its level-chunked
-        # staging with compute — see repro.core.device_store)
+        with self._readback():
+            dv = np.asarray(h.dev)  # synchronous transfer back (the
+        # sequential path; the device-resident path instead overlaps its
+        # level-chunked staging with compute — see repro.core.device_store)
         out[:h.w] = dv[:h.w, :h.w]
         out[h.w:] = dv[h.Wp:h.Wp + h.rows - h.w, :h.w]
-        self.stats["transfers_out"] += 1
         self.stats["bytes_out"] += out.nbytes
         return out
 
@@ -1046,26 +1076,27 @@ class DeviceEngine:
         if getattr(h, "_u", None) is not None:
             u = h._u
         else:
-            self.stats["device_calls"] += 1
-            u = self._syrk_tail_fn(h.Lp, h.Wp)(h.dev)
-        out = np.asarray(u)[:m, :m]
-        self.stats["transfers_out"] += 1
+            with self._dispatch():
+                u = self._syrk_tail_fn(h.Lp, h.Wp)(h.dev)
+        with self._readback():
+            out = np.asarray(u)[:m, :m]
         self.stats["bytes_out"] += out.nbytes
         return out
 
     def syrk_block(self, h: _Handle, k0: int, k1: int):
         nb = k1 - k0
         nbp = _bucket_nb(nb)
-        self.stats["device_calls"] += 1
-        u = self._syrk_block_fn(h.Lp, h.Wp, nbp)(h.dev, k0, nb)
-        return u[:nb, :nb]
+        with self._dispatch():
+            u = self._syrk_block_fn(h.Lp, h.Wp, nbp)(h.dev, k0, nb)
+            return u[:nb, :nb]
 
     def gemm_block(self, h: _Handle, kr0: int, kr1: int, kc0: int, kc1: int):
         nr, nc = kr1 - kr0, kc1 - kc0
         nrp, ncp = _bucket_nb(nr), _bucket_nb(nc)
-        self.stats["device_calls"] += 1
-        g = self._gemm_block_fn(h.Lp, h.Wp, nrp, ncp)(h.dev, kr0, nr, kc0, nc)
-        return g[:nr, :nc]
+        with self._dispatch():
+            g = self._gemm_block_fn(h.Lp, h.Wp, nrp, ncp)(h.dev, kr0, nr,
+                                                          kc0, nc)
+            return g[:nr, :nc]
 
     # -- batched protocol (level-scheduled path; see repro.core.schedule) ---
     #
@@ -1094,18 +1125,18 @@ class DeviceEngine:
         return _BatchHandle(dev, [P.shape[0] for P in Ps], list(ws), Lp, Wp, B)
 
     def factor_batch(self, hb: _BatchHandle) -> None:
-        self.stats["device_calls"] += 1
         Bp = hb.dev.shape[0]
         fn = self._batch_factor_syrk_fn(Bp, hb.Lp, hb.Wp)
         rows = np.zeros(Bp, np.int32)
         ws = np.zeros(Bp, np.int32)
         rows[:hb.B] = hb.rows
         ws[:hb.B] = hb.ws
-        hb.dev, hb._u = fn(hb.dev, rows, ws)
+        with self._dispatch():
+            hb.dev, hb._u = fn(hb.dev, rows, ws)
 
     def read_panels_batch(self, hb: _BatchHandle) -> list:
-        dv = jax.device_get(hb.dev)  # one bulk transfer for the whole batch
-        self.stats["transfers_out"] += 1
+        with self._readback():  # one bulk transfer for the whole batch
+            dv = jax.device_get(hb.dev)
         outs = []
         for i in range(hb.B):
             rows, w = hb.rows[i], hb.ws[i]
@@ -1121,8 +1152,8 @@ class DeviceEngine:
         ``None`` for supernodes with no tail).  One bulk transfer."""
         if hb._u is None or hb._u.shape[1] == 0:
             return [None] * hb.B
-        uv = jax.device_get(hb._u)
-        self.stats["transfers_out"] += 1
+        with self._readback():
+            uv = jax.device_get(hb._u)
         outs = []
         for i in range(hb.B):
             m = hb.rows[i] - hb.ws[i]
@@ -1150,39 +1181,39 @@ class DeviceEngine:
 
     def get(self, x) -> np.ndarray:
         """Device -> host transfer (counted; device-resident read-back)."""
-        out = np.asarray(jax.device_get(x))
-        self.stats["transfers_out"] += 1
+        with self._readback():
+            out = np.asarray(jax.device_get(x))
         self.stats["bytes_out"] += out.nbytes
         return out
 
     def gather_group(self, storage0, pool, g):
         """Build one group's stacked padded panel buffer on the device (see
         repro.core.device_store._DevGroup for ``g``).  Zero transfers."""
-        self.stats["device_calls"] += 1
-        fn = self._gather_group_fn(
-            g.Bp, g.Lp, g.Wp, int(g.cells.shape[0]), int(g.src.shape[0])
-        )
-        return fn(storage0, pool, g.cells, g.src, g.hi, g.pstart, g.rows,
-                  g.ws)
+        with self._dispatch():
+            fn = self._gather_group_fn(
+                g.Bp, g.Lp, g.Wp, int(g.cells.shape[0]), int(g.src.shape[0])
+            )
+            return fn(storage0, pool, g.cells, g.src, g.hi, g.pstart, g.rows,
+                      g.ws)
 
     def factor_group(self, buf, rows, ws):
         """One batched fused POTRF+TRSM+SYRK dispatch over a stacked buffer.
         ``rows``/``ws`` are the group's true per-lane extents (pad lanes 0),
         required by the masked kernel and ignored by the XLA program."""
-        self.stats["device_calls"] += 1
         Bp, Lp, Wp = buf.shape
-        return self._batch_factor_syrk_fn(Bp, Lp, Wp)(buf, rows, ws)
+        with self._dispatch():
+            return self._batch_factor_syrk_fn(Bp, Lp, Wp)(buf, rows, ws)
 
     def pack_group(self, fp, u, pool, g):
         """Pack one group's factored panels and update entries (in-place pool
         append).  Zero transfers."""
-        self.stats["device_calls"] += 1
         self._note_donation(pool)
         Bp, Lp, Wp = fp.shape
-        fn = self._pack_group_fn(
-            Bp, Lp, Wp, int(g.ppack.shape[0]), int(g.upack.shape[0])
-        )
-        return fn(fp, u, pool, g.ppack, g.upack, g.off)
+        with self._dispatch():
+            fn = self._pack_group_fn(
+                Bp, Lp, Wp, int(g.ppack.shape[0]), int(g.upack.shape[0])
+            )
+            return fn(fp, u, pool, g.ppack, g.upack, g.off)
 
     def _group_tiers(self, first: str) -> list:
         """Fallback chain for fused-group dispatch: the program the shape
@@ -1264,11 +1295,11 @@ class DeviceEngine:
         With ``guard`` the dispatch also returns the per-lane status rows
         (see kernels/fused.py STATUS_COLS); failures degrade through
         ``_run_group_chain``."""
-        self.stats["device_calls"] += 1
         self._note_donation(pool, lvl)
         self._event("dispatch", lvl)
-        return self._run_group_chain(False, chunk, pool, g, lvl,
-                                     guard, thr, clamp)
+        with self._dispatch():
+            return self._run_group_chain(False, chunk, pool, g, lvl,
+                                         guard, thr, clamp)
 
     def fused_group_many(self, chunk, pool, g, lvl: int = -1, *,
                          guard: bool = False, thr: float = 0.0,
@@ -1277,11 +1308,11 @@ class DeviceEngine:
         ``chunk``/``pool``) through one pattern's index arrays, factored as
         ONE dispatch of M*Bp lanes.  Zero transfers.  Guarded dispatches
         return (packed, pool, st) with st (M, Bp, STATUS_COLS)."""
-        self.stats["device_calls"] += 1
         self._note_donation(pool, lvl)
         self._event("dispatch", lvl)
-        return self._run_group_chain(True, chunk, pool, g, lvl,
-                                     guard, thr, clamp)
+        with self._dispatch():
+            return self._run_group_chain(True, chunk, pool, g, lvl,
+                                         guard, thr, clamp)
 
     def _host_fused_group(self, chunk, pool, g, *, many: bool, guard: bool,
                           thr: float, clamp: bool):
@@ -1290,16 +1321,17 @@ class DeviceEngine:
         tier raised, so the transfers it needs are counted honestly."""
         Bp, Lp, Wp = g.Bp, g.Lp, g.Wp
         mp = Lp - Wp
-        ch = np.asarray(jax.device_get(chunk), dtype=np.float64)
-        # device_get can hand back a read-only view of the device buffer;
-        # the pool is written below (update segments), so take a real copy
-        po = np.array(jax.device_get(pool), dtype=np.float64)
-        idx = {k: np.asarray(jax.device_get(getattr(g, k)))
-               for k in ("src", "hi", "pstart", "rows", "ws",
-                         "ppack", "upack")}
+        with self._readback():
+            ch = np.asarray(jax.device_get(chunk), dtype=np.float64)
+            # device_get can hand back a read-only view of the device
+            # buffer; the pool is written below (update segments), so take
+            # a real copy
+            po = np.array(jax.device_get(pool), dtype=np.float64)
+            idx = {k: np.asarray(jax.device_get(getattr(g, k)))
+                   for k in ("src", "hi", "pstart", "rows", "ws",
+                             "ppack", "upack")}
         idx["lo"] = np.concatenate([[0], idx["hi"][:-1]]).astype(
             idx["hi"].dtype)
-        self.stats["transfers_out"] += 1
         self.stats["bytes_out"] += ch.nbytes + po.nbytes
         lb, off = int(g.lb), int(g.off)
         r = idx["ppack"].shape[0]
@@ -1349,53 +1381,54 @@ class DeviceEngine:
 
     def invert_diag(self, P):
         """Invert one group's stacked diagonal blocks (finalize-time)."""
-        self.stats["device_calls"] += 1
-        return self._invert_diag_fn(*P.shape)(P)
+        with self._dispatch():
+            return self._invert_diag_fn(*P.shape)(P)
 
     def solve_fwd_level(self, y, trash, Ps, Dinvs, colss, tailss):
         """One forward-substitution level against the device-resident RHS."""
-        self.stats["device_calls"] += 1
         self._note_donation(y)
         shapes = tuple(P.shape for P in Ps)
-        return self._solve_fwd_fn(shapes, int(y.shape[1]), int(trash.shape[0]))(
-            y, trash, Ps, Dinvs, colss, tailss
-        )
+        with self._dispatch():
+            return self._solve_fwd_fn(shapes, int(y.shape[1]),
+                                      int(trash.shape[0]))(
+                y, trash, Ps, Dinvs, colss, tailss
+            )
 
     def solve_bwd_level(self, y, trash, Ps, Dinvs, colss, tailss):
         """One backward-substitution level against the device-resident RHS."""
-        self.stats["device_calls"] += 1
         self._note_donation(y)
         shapes = tuple(P.shape for P in Ps)
-        return self._solve_bwd_fn(shapes, int(y.shape[1]), int(trash.shape[0]))(
-            y, trash, Ps, Dinvs, colss, tailss
-        )
+        with self._dispatch():
+            return self._solve_bwd_fn(shapes, int(y.shape[1]),
+                                      int(trash.shape[0]))(
+                y, trash, Ps, Dinvs, colss, tailss
+            )
 
     def stage_rhs(self, b, iperm, trash):
         """Permute a device-resident RHS into the padded solve layout (zero
         transfers; counted as a device call)."""
-        self.stats["device_calls"] += 1
-        return self._stage_rhs_fn(int(b.shape[0]), int(trash.shape[0]))(
-            b, iperm, trash
-        )
+        with self._dispatch():
+            return self._stage_rhs_fn(int(b.shape[0]), int(trash.shape[0]))(
+                b, iperm, trash
+            )
 
     def unstage_rhs(self, y, operm):
         """Read the padded solve layout back to natural order on the device
         (zero transfers; counted as a device call)."""
-        self.stats["device_calls"] += 1
-        return self._unstage_rhs_fn(int(y.shape[0]), int(operm.shape[0]))(
-            y, operm
-        )
+        with self._dispatch():
+            return self._unstage_rhs_fn(int(y.shape[0]),
+                                        int(operm.shape[0]))(y, operm)
 
     def fetch(self, x) -> np.ndarray:
         """Per-result device->host transfer (RLB v2's per-block mode)."""
-        out = np.asarray(x)
-        self.stats["transfers_out"] += 1
+        with self._readback():
+            out = np.asarray(x)
         self.stats["bytes_out"] += out.nbytes
         return out
 
     def gather(self, xs) -> list:
-        out = jax.device_get(list(xs))  # one bulk transfer
-        self.stats["transfers_out"] += 1
+        with self._readback():
+            out = jax.device_get(list(xs))  # one bulk transfer
         self.stats["bytes_out"] += sum(int(np.asarray(x).nbytes) for x in out)
         return [np.asarray(x) for x in out]
 

@@ -55,6 +55,7 @@ import numpy as np
 import scipy.linalg as sla
 import scipy.sparse as sp
 
+from repro.core import counters
 from repro.core.relind import ancestor_updates, scatter_plan, supernode_blocks
 from repro.core.schedule import cached_schedule
 from repro.core.symbolic import SymbolicFactor
@@ -613,53 +614,54 @@ def _factorize_levels_device(
     None; otherwise the store is filled from ``Aperm`` as usual."""
     from repro.core.device_store import DevicePanelStore
 
-    _reset_events(device_engine)  # one event log per factorization
-    if store is None:
-        store = init_panel_store(sym, Aperm)
-    fused = bool(getattr(device_engine, "fused_groups", False))
-    bucket = ("fused"
-              if fused and getattr(device_engine, "backend", "") == "pallas"
-              else "batch")
-    sched = cached_schedule(sym, max_batch=max_batch, bucket=bucket)
-    dstore = DevicePanelStore(device_engine, sym, sched, store.storage,
-                              staging=staging, guard=guard is not None,
-                              guard_thr=guard_thr, guard_clamp=guard_clamp)
-    stats = {
-        "method": "levels",
-        "assembly": "device",
-        "staging": dstore.staging,
-        "bucket": bucket,
-        "dispatches_per_group": 1 if dstore.fused else 3,
-        "supernodes_on_device": sym.nsuper,
-        "supernodes_total": sym.nsuper,
-        "schedule": sched.batch_stats(),
-        "level_stats": [],
-    }
-    for lvl, lgroups in enumerate(sched.groups):
-        # double buffering: issue the next level's chunk upload BEFORE this
-        # level's dispatches block on compute
-        dstore.prefetch_level(lvl + 1)
-        lrec = {"level": lvl, "supernodes": 0, "batches": 0, "max_batch": 0,
-                "on_device": 0}
-        for gi, bg in enumerate(lgroups):
-            dstore.assemble_group(lvl, gi)
-            nb = int(bg.ids.shape[0])
-            lrec["batches"] += 1
-            lrec["supernodes"] += nb
-            lrec["on_device"] += nb
-            lrec["max_batch"] = max(lrec["max_batch"], nb)
-        stats["level_stats"].append(lrec)
-    dstore.read_into(store.storage)  # ONE bulk factor read-back
-    device_engine.flush()
-    report = None
-    if guard is not None:
-        report = _reduce_guard(sym, sched, dstore.guard_status(),
-                               mode=guard, thr=guard_thr)
-        stats["guard"] = guard
-    return CholeskyFactor(
-        sym=sym, panels=store.panels, stats=stats, store=store, dstore=dstore,
-        guard_report=report,
-    )
+    with counters.span("factor"):
+        _reset_events(device_engine)  # one event log per factorization
+        if store is None:
+            store = init_panel_store(sym, Aperm)
+        fused = bool(getattr(device_engine, "fused_groups", False))
+        pallas = getattr(device_engine, "backend", "") == "pallas"
+        bucket = "fused" if fused and pallas else "batch"
+        sched = cached_schedule(sym, max_batch=max_batch, bucket=bucket)
+        dstore = DevicePanelStore(device_engine, sym, sched, store.storage,
+                                  staging=staging, guard=guard is not None,
+                                  guard_thr=guard_thr,
+                                  guard_clamp=guard_clamp)
+        stats = {
+            "method": "levels",
+            "assembly": "device",
+            "staging": dstore.staging,
+            "bucket": bucket,
+            "dispatches_per_group": 1 if dstore.fused else 3,
+            "supernodes_on_device": sym.nsuper,
+            "supernodes_total": sym.nsuper,
+            "schedule": sched.batch_stats(),
+            "level_stats": [],
+        }
+        for lvl, lgroups in enumerate(sched.groups):
+            # double buffering: issue the next level's chunk upload BEFORE
+            # this level's dispatches block on compute
+            dstore.prefetch_level(lvl + 1)
+            lrec = {"level": lvl, "supernodes": 0, "batches": 0,
+                    "max_batch": 0, "on_device": 0}
+            for gi, bg in enumerate(lgroups):
+                dstore.assemble_group(lvl, gi)
+                nb = int(bg.ids.shape[0])
+                lrec["batches"] += 1
+                lrec["supernodes"] += nb
+                lrec["on_device"] += nb
+                lrec["max_batch"] = max(lrec["max_batch"], nb)
+            stats["level_stats"].append(lrec)
+        dstore.read_into(store.storage)  # ONE bulk factor read-back
+        device_engine.flush()
+        report = None
+        if guard is not None:
+            report = _reduce_guard(sym, sched, dstore.guard_status(),
+                                   mode=guard, thr=guard_thr)
+            stats["guard"] = guard
+        return CholeskyFactor(
+            sym=sym, panels=store.panels, stats=stats, store=store,
+            dstore=dstore, guard_report=report,
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -719,10 +721,11 @@ class BatchCholeskyFactor:
         squeeze = B.ndim == 2
         if squeeze:
             B = B[..., None]
-        X, _res, _hist = block_refine(
-            lambda X: np.stack([A @ x for A, x in zip(self.guard_As, X)]),
-            lambda R: device_solve(self.dstore, R), B,
-        )
+        with counters.span("refine"):
+            X, _res, _hist = block_refine(
+                lambda X: np.stack([A @ x for A, x in zip(self.guard_As, X)]),
+                lambda R: device_solve(self.dstore, R), B,
+            )
         return X[..., 0] if squeeze else X
 
 
@@ -746,48 +749,50 @@ def factorize_levels_device_many(
     once per (matrix, group)."""
     from repro.core.device_store import DevicePanelStore
 
-    _reset_events(device_engine)
-    M = int(storage.shape[0])
-    fused = bool(getattr(device_engine, "fused_groups", False))
-    if not fused:
-        raise ValueError("multi-matrix factorization requires fused groups")
-    bucket = ("fused"
-              if getattr(device_engine, "backend", "") == "pallas"
-              else "batch")
-    sched = cached_schedule(sym, max_batch=max_batch, bucket=bucket)
-    dstore = DevicePanelStore(device_engine, sym, sched, storage,
-                              staging=staging, nmat=M,
-                              guard=guard is not None, guard_thr=guard_thr,
-                              guard_clamp=guard_clamp)
-    stats = {
-        "method": "levels_many",
-        "assembly": "device",
-        "staging": dstore.staging,
-        "bucket": bucket,
-        "nmat": M,
-        "supernodes_on_device": sym.nsuper,
-        "supernodes_total": sym.nsuper,
-        "schedule": sched.batch_stats(),
-    }
-    for lvl, lgroups in enumerate(sched.groups):
-        dstore.prefetch_level(lvl + 1)
-        for gi in range(len(lgroups)):
-            dstore.assemble_group(lvl, gi)
-    dstore.read_into(storage)  # ONE bulk read-back of all M factors
-    device_engine.flush()
-    reports = None
-    if guard is not None:
-        stat = dstore.guard_status()
-        reports = [
-            _reduce_guard(sym, sched, [st[m] for st in stat],
-                          mode=guard, thr=guard_thr)
-            for m in range(M)
-        ]
-        stats["guard"] = guard
-    return BatchCholeskyFactor(
-        sym=sym, nmat=M, storage=storage, stats=stats, dstore=dstore,
-        guard_reports=reports,
-    )
+    with counters.span("factor"):
+        _reset_events(device_engine)
+        M = int(storage.shape[0])
+        fused = bool(getattr(device_engine, "fused_groups", False))
+        if not fused:
+            raise ValueError(
+                "multi-matrix factorization requires fused groups")
+        bucket = ("fused"
+                  if getattr(device_engine, "backend", "") == "pallas"
+                  else "batch")
+        sched = cached_schedule(sym, max_batch=max_batch, bucket=bucket)
+        dstore = DevicePanelStore(device_engine, sym, sched, storage,
+                                  staging=staging, nmat=M,
+                                  guard=guard is not None, guard_thr=guard_thr,
+                                  guard_clamp=guard_clamp)
+        stats = {
+            "method": "levels_many",
+            "assembly": "device",
+            "staging": dstore.staging,
+            "bucket": bucket,
+            "nmat": M,
+            "supernodes_on_device": sym.nsuper,
+            "supernodes_total": sym.nsuper,
+            "schedule": sched.batch_stats(),
+        }
+        for lvl, lgroups in enumerate(sched.groups):
+            dstore.prefetch_level(lvl + 1)
+            for gi in range(len(lgroups)):
+                dstore.assemble_group(lvl, gi)
+        dstore.read_into(storage)  # ONE bulk read-back of all M factors
+        device_engine.flush()
+        reports = None
+        if guard is not None:
+            stat = dstore.guard_status()
+            reports = [
+                _reduce_guard(sym, sched, [st[m] for st in stat],
+                              mode=guard, thr=guard_thr)
+                for m in range(M)
+            ]
+            stats["guard"] = guard
+        return BatchCholeskyFactor(
+            sym=sym, nmat=M, storage=storage, stats=stats, dstore=dstore,
+            guard_reports=reports,
+        )
 
 
 # ---------------------------------------------------------------------------

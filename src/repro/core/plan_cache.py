@@ -149,14 +149,13 @@ class CachedPlan:
         """Vectorized PanelStore fill: permute + scatter A's values into the
         flat storage layout with one gather (``row`` writes into an existing
         storage row in place — the multi-matrix staging path)."""
-        data = self.values_of(A)
-        if row is not None:
+        with counters.span("fill"):
+            data = self.values_of(A)
+            if row is None:
+                row = out if out is not None else np.zeros(
+                    int(self.sym.plan.storage_cells), dtype=np.float64)
             row[self.fill_dst] = data[self.fill_src]
             return row
-        if out is None:
-            out = np.zeros(int(self.sym.plan.storage_cells), dtype=np.float64)
-        out[self.fill_dst] = data[self.fill_src]
-        return out
 
     def values_of(self, A: sp.spmatrix) -> np.ndarray:
         """Canonical-CSC data array of ``A``, pattern-checked against this

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.core import counters
 from repro.core.symbolic import SymbolicFactor
 
 
@@ -171,43 +172,45 @@ def refine_solve(F, A, b, *, x0=None, tol=1e-12, max_iter=None,
     Returns ``(x, hist)`` where ``hist`` is the relative-residual trajectory
     (max over RHS columns for multi-RHS ``b``).
     """
-    b = np.asarray(b, dtype=np.float64)
-    squeeze = b.ndim == 1
-    B = b[:, None] if squeeze else b
-    if max_iter is None:
-        # full-basis GMRES terminates exactly within n steps; rank-p
-        # perturbations (guard='perturb') need only p + 1 — budget 2p plus
-        # slack for finite-precision drag — while full-rank shifts
-        # (guard='shift') may need the spectrum-driven worst case
-        rep = getattr(F, "guard_report", None)
-        p = sum(q["n_clamped"] for q in rep.perturbations) if rep else 0
-        if rep is not None and p and not rep.shift:
-            max_iter = int(min(B.shape[0], max(2 * p + 30, 100)))
-        else:
-            max_iter = int(min(B.shape[0], 300))
+    with counters.span("refine"):
+        b = np.asarray(b, dtype=np.float64)
+        squeeze = b.ndim == 1
+        B = b[:, None] if squeeze else b
+        if max_iter is None:
+            # full-basis GMRES terminates exactly within n steps; rank-p
+            # perturbations (guard='perturb') need only p + 1 — budget 2p
+            # plus slack for finite-precision drag — while full-rank shifts
+            # (guard='shift') may need the spectrum-driven worst case
+            rep = getattr(F, "guard_report", None)
+            p = sum(q["n_clamped"] for q in rep.perturbations) if rep else 0
+            if rep is not None and p and not rep.shift:
+                max_iter = int(min(B.shape[0], max(2 * p + 30, 100)))
+            else:
+                max_iter = int(min(B.shape[0], 300))
 
-    def psolve(v):
-        return np.asarray(
-            F.solve(v, backend=backend, engine=engine, refine=False)
-        )
+        def psolve(v):
+            return np.asarray(
+                F.solve(v, backend=backend, engine=engine, refine=False)
+            )
 
-    X0 = None if x0 is None else np.asarray(x0, dtype=np.float64).reshape(
-        B.shape)
-    X, res, hist = block_refine(lambda V: A @ V, psolve, B, X0, tol=tol)
-    hists = []
-    for j in np.flatnonzero(res > tol):
-        X[:, j], hj = _refine_one(A, B[:, j], X[:, j], psolve, tol, max_iter)
-        res[j] = hj[-1]
-        hists.append(hj)
-    if hists:
-        # entry i = worst GMRES column at stage i, floored at the block's
-        # converged columns; the last entry is the final max residual
-        done = float(np.max(res, initial=0.0))
-        depth = max(len(h) for h in hists)
-        hist += [max(max(h[min(i, len(h) - 1)] for h in hists),
-                     done if i == depth - 1 else 0.0)
-                 for i in range(depth)]
-    return (X[:, 0] if squeeze else X), hist
+        X0 = (None if x0 is None
+              else np.asarray(x0, dtype=np.float64).reshape(B.shape))
+        X, res, hist = block_refine(lambda V: A @ V, psolve, B, X0, tol=tol)
+        hists = []
+        for j in np.flatnonzero(res > tol):
+            X[:, j], hj = _refine_one(A, B[:, j], X[:, j], psolve, tol,
+                                      max_iter)
+            res[j] = hj[-1]
+            hists.append(hj)
+        if hists:
+            # entry i = worst GMRES column at stage i, floored at the block's
+            # converged columns; the last entry is the final max residual
+            done = float(np.max(res, initial=0.0))
+            depth = max(len(h) for h in hists)
+            hist += [max(max(h[min(i, len(h) - 1)] for h in hists),
+                         done if i == depth - 1 else 0.0)
+                     for i in range(depth)]
+        return (X[:, 0] if squeeze else X), hist
 
 
 def _refine_one(A, b, x0, psolve, tol, max_iter):

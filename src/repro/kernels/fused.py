@@ -55,6 +55,10 @@ _I0 = np.int32(0)
 #: program before anything is dispatched.
 VMEM_LIMIT = 16 * 2 ** 20
 
+#: the kernel's ``pallas_call`` name: a profiler trace shows its custom call
+#: under this name
+KERNEL_NAME = "fused_factor_syrk"
+
 
 def syrk_tile(mp: int, cap: int = 128) -> int:
     """SYRK column-tile width for a bucket tail of ``mp`` rows.  The u
@@ -387,6 +391,7 @@ def fused_factor_syrk(
         grid_spec=grid_spec,
         out_shape=out_shapes,
         interpret=interpret,
+        name=KERNEL_NAME,
         **kw,
     )
     if guard:

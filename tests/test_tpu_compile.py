@@ -7,6 +7,8 @@ The device configuration the library picks from the platform (float32
 values, the compiled kernel) is steered in here, since JAX in this process
 sees only the CPU.  The topology is described in a module fixture, never at
 import, so every test worker collects the same tests."""
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -136,5 +138,15 @@ def test_xla_group_program_compiles_past_bound(chip, on_chip):
             _sds(chip, (Bp,), i32), _sds(chip, (Bp,), i32),
             _sds(chip, (Bp,), i32), _sds(chip, (r,), i32),
             _sds(chip, (n_out,), i32), _sds(chip, (), f32)]
-    compiled = fn.lower(*args).compile()
-    assert "tpu_custom_call" not in compiled.as_text()
+    text = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" not in text
+    for scope in ("chol.assemble", "chol.segment_ids", "chol.xla_factor"):
+        assert scope in text
+
+
+def test_kernel_custom_call_carries_its_name(chip, on_chip):
+    text = _compile_kernel(chip, 16, 576, 64, guard=False).as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert calls
+    for line in calls:
+        assert re.search(rf"%{fused.KERNEL_NAME}(\.\d+)? = ", line)
