@@ -294,7 +294,7 @@ def lint_schedule(sym, sched, *, bucket: str | None = None) -> list:
 def _pool_destinations(sym, sched, gp) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Re-derive, for every update-pool slot, (a) its destination cell in the
     packed factor and (b) its producer group — straight from the scatter
-    plan, independent of the src/lo/hi arrays under test.  Also returns the
+    plan, independent of the src/seg arrays under test.  Also returns the
     per-group pool offsets (walk order)."""
     plan = scatter_plan(sym)
     offs = np.asarray(plan.offs, dtype=np.int64)
@@ -388,8 +388,7 @@ def lint_device_plan(sym, sched, gp=None) -> list:
                             f"off {int(g.off)}, want {int(pool_off[k])}"))
         pos += r
         src = np.asarray(g.src, dtype=np.int64)
-        lo = np.asarray(g.lo, dtype=np.int64)
-        hi = np.asarray(g.hi, dtype=np.int64)
+        seg = np.asarray(g.seg, dtype=np.int64)
         src_all.append(src)
         if src.size and (src.min() < 0 or src.max() >= dest.shape[0]):
             out.append(_err("src-oob", loc,
@@ -398,16 +397,17 @@ def lint_device_plan(sym, sched, gp=None) -> list:
                             f"pool {dest.shape[0]}"))
             continue
         n_in = src.shape[0]
-        seg_ok = (lo.shape == (r,) and hi.shape == (r,)
-                  and (r == 0 or (lo[0] == 0 and hi[-1] == n_in))
-                  and np.all(hi >= lo) and np.array_equal(lo[1:], hi[:-1]))
+        seg_ok = (seg.shape == (n_in,)
+                  and (n_in == 0 or (seg[0] >= 0 and seg[-1] < r))
+                  and np.all(np.diff(seg) >= 0))
         if not seg_ok:
             out.append(_err("segment-bounds", loc,
-                            "lo/hi tile [0, n_in) contiguously per packed cell"))
+                            "seg holds one non-decreasing packed-cell id in "
+                            "[0, r) per incoming entry"))
             continue
-        # the load-bearing check: slot k of segment i must be an update entry
-        # whose scatter-plan destination IS packed cell base+i
-        want = int(g.base) + np.repeat(np.arange(r), hi - lo)
+        # the load-bearing check: slot k must be an update entry whose
+        # scatter-plan destination IS packed cell base+seg[k]
+        want = int(g.base) + seg
         if not np.array_equal(dest[src], want):
             k_bad = int(np.flatnonzero(dest[src] != want)[0])
             out.append(_err(

@@ -47,7 +47,6 @@ each scoped program is jitted under a name of its own, which the trace's
 ``XLA Modules`` line shows.  The scopes:
 
     chol.assemble     a group's pool gather, update sums, panel gather, pack
-    chol.segment_ids  the segment ids of the update sums (inside assemble)
     chol.xla_factor   the XLA factor of a group program (POTRF+TRSM+SYRK)
     chol.finalize     the device factor's concatenation, its panel gathers
                       and diagonal inversions

@@ -26,12 +26,13 @@ So update matrices go to a preallocated device *pool* (packed real entries,
 one contiguous ``dynamic_update_slice`` per group), and when a group is
 gathered its pending contributions are applied as per-cell sums: with the
 incoming pool entries gathered in destination order, one sorted segment sum
-(``engines.segment_sums``) adds each cell's entries on their own, so each
-sum's rounding error is relative to that cell's contributions.  (The
-difference of two prefixes of one running sum, ``C[hi] - C[lo]``, would
-carry error relative to the whole group's total, which cancels in
-float32.)  Factored panels are packed per group (a gather) and concatenated
-once into the contiguous device factor the solve programs index.
+over the plan's segment ids (``engines.segment_sums``) adds each cell's
+entries on their own, so each sum's rounding error is relative to that
+cell's contributions.  (The difference of two prefixes of one running sum,
+``C[hi] - C[lo]``, would carry error relative to the whole group's total,
+which cancels in float32.)  Factored panels are packed per group (a
+gather) and concatenated once into the contiguous device factor the solve
+programs index.
 
 Values are stored on the device as the engine's dtype (``eng.dtype``,
 float32 on TPU); the host copies stay float64.
@@ -47,8 +48,10 @@ LevelSchedule:
                       per lane)
     src (n,)          pool position of every incoming update entry, sorted
                       by destination packed cell
-    lo / hi (r,)      segment bounds of each packed cell's contributions (only
-                      ``hi`` is staged: ``lo`` is ``hi`` shifted by one)
+    seg (n,)          segment id of every incoming entry: the packed cell
+                      (0..r-1) it updates, non-decreasing; the device sums
+                      by it directly.  ``hi``/``lo`` (r,), each cell's
+                      segment end and start, are derived on the host
     gidx (Bp,Lp,Wp)   index into the zero/one-extended packed vector that
                       reproduces the stacked padded panel buffer (pad cells
                       -> the zero cell r, identity diagonals -> the one cell
@@ -101,7 +104,7 @@ class GroupIndices:
     lb: int                # slice start within the level's packed chunk
     cells: np.ndarray      # (r,)
     src: np.ndarray        # (n,)
-    hi: np.ndarray         # (r,)
+    seg: np.ndarray        # (n,)
     ppack: np.ndarray      # (r,)
     upack: np.ndarray      # (n_out,)
     rows_arr: np.ndarray   # (Bp,) true row count per lane (pad lanes 0)
@@ -111,9 +114,18 @@ class GroupIndices:
     tails: np.ndarray      # (Bp, Lp-Wp)
 
     @property
+    def hi(self) -> np.ndarray:
+        """(r,) segment ends: packed cell c's incoming entries are
+        ``src[lo[c]:hi[c]]``."""
+        r = self.cells.shape[0]
+        return np.cumsum(np.bincount(self.seg, minlength=r)).astype(
+            self.seg.dtype)
+
+    @property
     def lo(self) -> np.ndarray:
         """(r,) segment starts: ``hi`` shifted right by one."""
-        return np.concatenate([np.zeros(1, self.hi.dtype), self.hi[:-1]])
+        hi = self.hi
+        return np.concatenate([np.zeros(1, hi.dtype), hi[:-1]])
 
     @property
     def gidx(self) -> np.ndarray:
@@ -247,13 +259,12 @@ def build_device_plan(sym: SymbolicFactor, sched: LevelSchedule) -> DeviceGroupP
             upack = (np.concatenate(upacks).astype(idx_t)
                      if upacks else np.empty(0, dtype=idx_t))
             src = order[grp_lo[gi]:grp_hi[gi]].astype(idx_t)
-            pp_in = sorted_pos[grp_lo[gi]:grp_hi[gi]] - base
-            hi = np.cumsum(np.bincount(pp_in, minlength=r)).astype(idx_t)
+            seg = (sorted_pos[grp_lo[gi]:grp_hi[gi]] - base).astype(idx_t)
             lvl_out.append(GroupIndices(
                 level=bg.level, Lp=Lp, Wp=Wp, B=B, Bp=Bp,
                 base=base, off=int(pool_off[gi]),
                 lb=int(base - level_base[bg.level]),
-                cells=cells, src=src, hi=hi,
+                cells=cells, src=src, seg=seg,
                 ppack=ppack, upack=upack,
                 rows_arr=rows_arr, ws_arr=ws_arr, pstart=pstart,
                 cols=cols, tails=tails,
@@ -309,13 +320,13 @@ def device_plan(sym: SymbolicFactor, sched: LevelSchedule) -> DeviceGroupPlan:
 class _DevGroup:
     """One group's index arrays as device-resident buffers, plus its static
     bucket shape (Bp, Lp, Wp) and packed cell count r."""
-    __slots__ = ("cells", "src", "hi", "pstart", "ppack", "upack",
+    __slots__ = ("cells", "src", "seg", "pstart", "ppack", "upack",
                  "rows", "ws", "cols", "tails", "off", "base", "lb",
                  "Bp", "Lp", "Wp", "r", "P", "Dinv")
 
-    def __init__(self, cells, src, hi, pstart, ppack, upack, rows, ws,
+    def __init__(self, cells, src, seg, pstart, ppack, upack, rows, ws,
                  cols, tails, off, base, lb, Bp, Lp, Wp, r):
-        self.cells, self.src, self.hi = cells, src, hi
+        self.cells, self.src, self.seg = cells, src, seg
         self.pstart, self.ppack, self.upack = pstart, ppack, upack
         self.rows, self.ws = rows, ws
         self.cols, self.tails = cols, tails
@@ -327,7 +338,7 @@ class _DevGroup:
     def drop_assembly(self) -> None:
         """Free the factorization-only index buffers (the solve keeps
         extents, cols and tails)."""
-        self.cells = self.src = self.hi = self.ppack = self.upack = None
+        self.cells = self.src = self.seg = self.ppack = self.upack = None
 
 
 class DevicePanelStore:
@@ -434,10 +445,10 @@ class DevicePanelStore:
         if factored:
             kinds = ("pstart", "rows_arr", "ws_arr", "cols", "tails")
         elif self.fused:  # the fused program never indexes raw storage cells
-            kinds = ("src", "hi", "pstart", "ppack", "upack",
+            kinds = ("src", "seg", "pstart", "ppack", "upack",
                      "rows_arr", "ws_arr", "cols", "tails")
         else:
-            kinds = ("cells", "src", "hi", "pstart", "ppack", "upack",
+            kinds = ("cells", "src", "seg", "pstart", "ppack", "upack",
                      "rows_arr", "ws_arr", "cols", "tails")
         parts = [getattr(g, k).ravel()
                  for lvl in gp.groups for g in lvl for k in kinds]
@@ -460,7 +471,7 @@ class DevicePanelStore:
                 row.append(_DevGroup(
                     cells=devs.get("cells", empty),
                     src=devs.get("src", empty),
-                    hi=devs.get("hi", empty),
+                    seg=devs.get("seg", empty),
                     pstart=devs["pstart"],
                     ppack=devs.get("ppack", empty),
                     upack=devs.get("upack", empty),

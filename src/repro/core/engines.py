@@ -125,24 +125,15 @@ def panel_index(xp, pstart, rows, ws, Lp: int, Wp: int, r: int):
     return xp.where((ri == ci) & (ri >= w), r + 1, idx).astype(it)
 
 
-def segment_sums(vals, hi):
+def segment_sums(vals, seg, r: int):
     """Per-cell sums of destination-sorted update entries along the last
-    axis: cell c owns ``vals[..., lo[c]:hi[c]]`` with ``lo`` = ``hi``
-    shifted right by one.  Each cell's entries are added on their own (a
-    sorted segment sum), so a sum's rounding error is relative to that
-    cell's contributions.  (A difference of two prefixes of one running
-    sum, ``C[hi] - C[lo]``, is relative to the group's whole running total
-    instead: it costs about a digit in float64 and cancels outright in
-    float32.)"""
-    n = vals.shape[-1]
-    r = hi.shape[0]
-    # segment id of every entry: the first cell whose end lies past it
-    # (empty cells are skipped).  A binary search, not a cumsum of segment
-    # starts: the TPU compiler takes 2-26 s on a cumsum of 1e5-1e7
-    # entries, against about 1 s on this search.
-    with counters.scope("segment_ids"):
-        seg = jnp.searchsorted(hi, jnp.arange(n, dtype=hi.dtype),
-                               side="right", method="scan")
+    axis: entry k belongs to packed cell ``seg[k]`` (non-decreasing, in
+    ``[0, r)``; the plan's segment ids, ``GroupIndices.seg``).  Each cell's
+    entries are added on their own (a sorted segment sum), so a sum's
+    rounding error is relative to that cell's contributions.  (A difference
+    of two prefixes of one running sum, ``C[hi] - C[lo]``, is relative to
+    the group's whole running total instead: it costs about a digit in
+    float64 and cancels outright in float32.)"""
 
     def one(v):
         return jax.ops.segment_sum(v, seg, num_segments=r,
@@ -698,28 +689,29 @@ class DeviceEngine:
     #
     # The device-resident numeric phase is deliberately SCATTER-FREE: XLA
     # lowers scatter to a serial per-element loop on CPU (and it is slow on
-    # TPU too), so assembly is reformulated as gathers + one running-sum
-    # trick.  Update matrices are never scattered into ancestor storage;
+    # TPU too), so assembly is reformulated as gathers + one sorted segment
+    # sum.  Update matrices are never scattered into ancestor storage;
     # instead each group's real update entries are packed (a gather) into a
     # preallocated device *pool* (a contiguous dynamic_update_slice), and
     # when an ancestor group is later gathered, its pending contributions are
     # summed by destination cell: with the group's incoming pool entries
-    # gathered in destination order, one sorted segment sum
-    # (``segment_sums``) yields every cell's sum.  Factored panels are
-    # likewise never written back to flat storage: they are packed (a gather)
-    # per group and concatenated at the end into the device-resident factor
-    # the solve programs read.  All index arrays are host-precomputed
-    # (repro.core.device_store.build_device_plan) and staged once.
+    # gathered in destination order, one sorted segment sum over the plan's
+    # segment ids (``segment_sums``) yields every cell's sum.  Factored
+    # panels are likewise never written back to flat storage: they are
+    # packed (a gather) per group and concatenated at the end into the
+    # device-resident factor the solve programs read.  All index arrays
+    # are host-precomputed (repro.core.device_store.build_device_plan) and
+    # staged once.
     def _gather_group_fn(self, Bp: int, Lp: int, Wp: int, r: int, n: int):
         """Build one group's stacked padded panel buffer from the initial
         storage and the update pool: storage gather, contribution segment
         sums, zero/one extension, padded-layout gather."""
 
-        def f(storage0, pool, cells, src, hi, pstart, rows, ws):
+        def f(storage0, pool, cells, src, seg, pstart, rows, ws):
             pc = storage0[cells]  # (r,) the group's panel cells, packed
             if n:
                 # incoming update entries, destination-sorted
-                pc = pc - segment_sums(pool[src], hi)
+                pc = pc - segment_sums(pool[src], seg, r)
             ext = jnp.concatenate(
                 [pc, jnp.zeros(1, pc.dtype), jnp.ones(1, pc.dtype)]
             )
@@ -772,11 +764,11 @@ class DeviceEngine:
         one_g = self._one_factor_syrk_guarded(Lp, Wp, clamp) if guard else None
 
         @counters.scope("assemble")
-        def gather(chunk, pool, lb, src, hi, pstart, rows, ws):
+        def gather(chunk, pool, lb, src, seg, pstart, rows, ws):
             pc = jax.lax.dynamic_slice(chunk, (lb,), (r,))
             if n_in:
                 # incoming update entries, destination-sorted
-                pc = pc - segment_sums(pool[src], hi)
+                pc = pc - segment_sums(pool[src], seg, r)
             ext = jnp.concatenate(
                 [pc, jnp.zeros(1, pc.dtype), jnp.ones(1, pc.dtype)]
             )
@@ -794,9 +786,9 @@ class DeviceEngine:
 
         if guard:
 
-            def fused_group(chunk, pool, lb, off, src, hi, pstart, rows,
+            def fused_group(chunk, pool, lb, off, src, seg, pstart, rows,
                             ws, ppack, upack, thr):
-                buf = gather(chunk, pool, lb, src, hi, pstart, rows, ws)
+                buf = gather(chunk, pool, lb, src, seg, pstart, rows, ws)
                 if backend == "pallas":
                     fp, u, st = fused_factor_syrk(
                         buf, rows, ws, interpret=kops._interpret(),
@@ -812,9 +804,9 @@ class DeviceEngine:
 
         else:
 
-            def fused_group(chunk, pool, lb, off, src, hi, pstart, rows,
+            def fused_group(chunk, pool, lb, off, src, seg, pstart, rows,
                             ws, ppack, upack):
-                buf = gather(chunk, pool, lb, src, hi, pstart, rows, ws)
+                buf = gather(chunk, pool, lb, src, seg, pstart, rows, ws)
                 if backend == "pallas":
                     fp, u = fused_factor_syrk(
                         buf, rows, ws, interpret=kops._interpret()
@@ -847,11 +839,11 @@ class DeviceEngine:
         one_g = self._one_factor_syrk_guarded(Lp, Wp, clamp) if guard else None
 
         @counters.scope("assemble")
-        def gather(chunk, pool, lb, src, hi, pstart, rows, ws):
+        def gather(chunk, pool, lb, src, seg, pstart, rows, ws):
             pc = jax.lax.dynamic_slice(chunk, (jnp.zeros_like(lb), lb), (M, r))
             if n_in:
                 # (M, n_in) destination-sorted entries
-                pc = pc - segment_sums(pool[:, src], hi)
+                pc = pc - segment_sums(pool[:, src], seg, r)
             ext = jnp.concatenate(
                 [pc, jnp.zeros((M, 1), pc.dtype), jnp.ones((M, 1), pc.dtype)],
                 axis=1,
@@ -870,9 +862,9 @@ class DeviceEngine:
 
         if guard:
 
-            def fused_group_many(chunk, pool, lb, off, src, hi, pstart,
+            def fused_group_many(chunk, pool, lb, off, src, seg, pstart,
                                  rows, ws, ppack, upack, thr):
-                buf = gather(chunk, pool, lb, src, hi, pstart, rows, ws)
+                buf = gather(chunk, pool, lb, src, seg, pstart, rows, ws)
                 if backend == "pallas":
                     fp, u, st = fused_factor_syrk(
                         buf, jnp.tile(rows, M), jnp.tile(ws, M),
@@ -888,9 +880,9 @@ class DeviceEngine:
 
         else:
 
-            def fused_group_many(chunk, pool, lb, off, src, hi, pstart,
+            def fused_group_many(chunk, pool, lb, off, src, seg, pstart,
                                  rows, ws, ppack, upack):
-                buf = gather(chunk, pool, lb, src, hi, pstart, rows, ws)
+                buf = gather(chunk, pool, lb, src, seg, pstart, rows, ws)
                 if backend == "pallas":
                     fp, u = fused_factor_syrk(
                         buf, jnp.tile(rows, M), jnp.tile(ws, M),
@@ -1193,7 +1185,7 @@ class DeviceEngine:
             fn = self._gather_group_fn(
                 g.Bp, g.Lp, g.Wp, int(g.cells.shape[0]), int(g.src.shape[0])
             )
-            return fn(storage0, pool, g.cells, g.src, g.hi, g.pstart, g.rows,
+            return fn(storage0, pool, g.cells, g.src, g.seg, g.pstart, g.rows,
                       g.ws)
 
     def factor_group(self, buf, rows, ws):
@@ -1254,7 +1246,7 @@ class DeviceEngine:
         If every tier fails, the first error propagates."""
         first = ("pallas" if self.uses_kernel(g.Lp, g.Wp, guard=guard)
                  else "xla")
-        args = (chunk, pool, g.lb, g.off, g.src, g.hi, g.pstart,
+        args = (chunk, pool, g.lb, g.off, g.src, g.seg, g.pstart,
                 g.rows, g.ws, g.ppack, g.upack)
         if guard:
             args += (thr,)
@@ -1328,10 +1320,8 @@ class DeviceEngine:
             # a real copy
             po = np.array(jax.device_get(pool), dtype=np.float64)
             idx = {k: np.asarray(jax.device_get(getattr(g, k)))
-                   for k in ("src", "hi", "pstart", "rows", "ws",
+                   for k in ("src", "seg", "pstart", "rows", "ws",
                              "ppack", "upack")}
-        idx["lo"] = np.concatenate([[0], idx["hi"][:-1]]).astype(
-            idx["hi"].dtype)
         self.stats["bytes_out"] += ch.nbytes + po.nbytes
         lb, off = int(g.lb), int(g.off)
         r = idx["ppack"].shape[0]
@@ -1344,9 +1334,8 @@ class DeviceEngine:
         for mi in range(M):
             pc = ch[mi, lb:lb + r].copy()
             if idx["src"].size:
-                vals = po[mi, idx["src"]]
-                C = np.concatenate([[0.0], np.cumsum(vals)])
-                pc -= C[idx["hi"]] - C[idx["lo"]]
+                pc -= np.bincount(idx["seg"], weights=po[mi, idx["src"]],
+                                  minlength=r)
             ext = np.concatenate([pc, [0.0], [1.0]])
             buf = ext[panel_index(np, idx["pstart"], idx["rows"], idx["ws"],
                                   Lp, Wp, r)]        # (Bp, Lp, Wp)

@@ -50,8 +50,9 @@ from repro.core.symbolic import SymbolicFactor
 #: bump when the CachedPlan layout changes; stale files are rejected on load.
 #: v2 wraps the payload in an envelope {version, key, digest, blob} whose
 #: blake2b digest detects corrupt/tampered files before anything is unpickled
-#: into the numeric phase.
-FORMAT_VERSION = 2
+#: into the numeric phase.  v3: the device index plans carry each group's
+#: segment ids (``GroupIndices.seg``) in place of the segment ends ``hi``.
+FORMAT_VERSION = 3
 
 
 def canonical_csc(A: sp.spmatrix) -> sp.csc_matrix:

@@ -207,6 +207,39 @@ def test_device_plan_segment_swap_caught(lap_sym, lap_sched, lap_gp):
     pytest.skip("no group with a multi-segment pool slice")
 
 
+def test_device_plan_unsorted_seg_caught(lap_sym, lap_sched, lap_gp):
+    # the sorted segment sum trusts the ids to be non-decreasing
+    gp = copy.deepcopy(lap_gp)
+    for lg in gp.groups:
+        for g in lg:
+            if g.seg.shape[0] >= 2 and g.seg[0] != g.seg[-1]:
+                g.seg[0], g.seg[-1] = int(g.seg[-1]), int(g.seg[0])
+                codes = _codes(_errors(
+                    lint_device_plan(lap_sym, lap_sched, gp)))
+                assert "segment-bounds" in codes
+                return
+    pytest.skip("no group with a multi-segment pool slice")
+
+
+@pytest.mark.parametrize("bad", ["r", "-1"])
+def test_device_plan_seg_out_of_range_caught(lap_sym, lap_sched, lap_gp, bad):
+    # an id past the group's packed cells (or below 0) sums an entry into
+    # no cell of this group
+    gp = copy.deepcopy(lap_gp)
+    for lg in gp.groups:
+        for g in lg:
+            if g.seg.shape[0]:
+                if bad == "r":
+                    g.seg[-1] = g.cells.shape[0]
+                else:
+                    g.seg[0] = -1
+                codes = _codes(_errors(
+                    lint_device_plan(lap_sym, lap_sched, gp)))
+                assert "segment-bounds" in codes
+                return
+    pytest.skip("no group with incoming updates")
+
+
 def test_device_plan_lost_update_caught(lap_sym, lap_sched, lap_gp):
     gp = copy.deepcopy(lap_gp)
     for lg in gp.groups:
@@ -443,6 +476,29 @@ def test_cache_get_rejects_and_rebuilds(saved_plan):
     assert p2.key == plan.key
     findings, _ = check_plan_file(path)  # the rewrite is clean again
     assert _errors(findings) == []
+
+
+def test_cache_get_rejects_and_rebuilds_v2_plan(saved_plan):
+    # a plan file of format 2 (segment ends ``hi`` in place of the segment
+    # ids ``seg``) is rejected, never loaded, and rebuilt in the current
+    # format
+    A, plan, path = saved_plan
+    env = pickle.loads(path.read_bytes())
+    assert env["version"] == 3
+    env["version"] = 2
+    path.write_bytes(pickle.dumps(env))
+    cache = PlanCache(path.parent)
+    p2 = cache.get(A)
+    assert cache.disk_rejects == 1
+    assert cache.stats["misses"] == 1 and cache.stats["disk_hits"] == 0
+    assert p2.key == plan.key
+    assert pickle.loads(path.read_bytes())["version"] == 3
+    gp = device_plan(p2.sym, cached_schedule(p2.sym, max_batch=256,
+                                             bucket="batch"))
+    groups = [g for lg in gp.groups for g in lg]
+    assert groups and all("seg" in vars(g) and "hi" not in vars(g)
+                          for g in groups)
+    assert any(g.seg.shape[0] for g in groups)
 
 
 def test_load_with_lint_gate(saved_plan):

@@ -111,7 +111,6 @@ def test_scopes_are_in_the_compiled_programs(monkeypatch):
     # program kind -> (its jitted name, its scopes): a name of its own keeps
     # a cached executable with other op_names from being served for it
     want = {"fused_group": ("fused_group", ("chol.assemble",
-                                            "chol.segment_ids",
                                             "chol.xla_factor")),
             "panels": ("finalize_panels", ("chol.finalize",)),
             "invert_diag": ("invert_diag", ("chol.finalize",)),
@@ -124,6 +123,8 @@ def test_scopes_are_in_the_compiled_programs(monkeypatch):
                    for t in texts[kind]), kind
         for scope in scopes:
             assert any(scope in t for t in texts[kind]), (kind, scope)
+    # the segment ids come from the plan: no search, no scope of its own
+    assert not any("chol.segment_ids" in t for t in texts["fused_group"])
     split = device_store._split_plan.lower(jnp.arange(6), ((0, (2,)), (2, (4,))))
     assert "chol.stage" in split.compile().as_text()
     ext = device_store._factor_ext.lower([jnp.ones(3)], np.zeros(2))

@@ -129,12 +129,14 @@ def test_segment_sums_exact_per_cell_in_f32():
     counts = rng.integers(0, 5, size=4000)
     counts[:50] = 3
     hi = np.cumsum(counts).astype(np.int32)
+    seg = np.repeat(np.arange(counts.shape[0], dtype=np.int32), counts)
+    r = counts.shape[0]
     vals = rng.uniform(0.5, 1.5, size=int(hi[-1]))
     vals[: int(hi[49])] *= 1e6   # a huge head of the running sum
     lo = np.concatenate([[0], hi[:-1]])
     exact = np.array([vals[a:b].sum() for a, b in zip(lo, hi)])
     got = np.asarray(segment_sums(jnp.asarray(vals, jnp.float32),
-                                  jnp.asarray(hi)))
+                                  jnp.asarray(seg), r))
     tail = slice(50, None)
     np.testing.assert_allclose(got[tail], exact[tail], rtol=1e-6, atol=1e-6)
     C = np.concatenate([[0.0], np.cumsum(vals.astype(np.float32),
@@ -143,8 +145,72 @@ def test_segment_sums_exact_per_cell_in_f32():
     assert np.max(np.abs(prefix[tail] - exact[tail])) > 1e-2
     # and the multi-matrix (leading axis) form agrees row by row
     two = np.asarray(segment_sums(jnp.asarray(np.stack([vals, 2 * vals])),
-                                  jnp.asarray(hi)))
+                                  jnp.asarray(seg), r))
     np.testing.assert_allclose(two[1], 2 * two[0], rtol=1e-12)
+
+
+def _test_plans():
+    """Device plans of a Laplacian and an elasticity pattern, both bucket
+    families."""
+    for A in (laplacian_3d(5), elasticity_3d(3)):
+        sym, _ = symbolic_pipeline(A)
+        for bucket in ("fused", "batch"):
+            yield build_device_plan(sym, cached_schedule(sym, bucket=bucket))
+
+
+def test_plan_segment_ids_are_searched_ids():
+    """The plan's segment ids are the ones a search of the segment ends
+    gives: sorted, in [0, r), one per incoming entry."""
+    seen = 0
+    for gp in _test_plans():
+        for g in (g for lvl in gp.groups for g in lvl):
+            n_in, r = g.src.shape[0], g.cells.shape[0]
+            assert g.seg.shape == (n_in,)
+            searched = np.searchsorted(g.hi, np.arange(n_in), side="right")
+            np.testing.assert_array_equal(g.seg, searched)
+            assert np.all(np.diff(g.seg) >= 0)
+            assert n_in == 0 or (g.seg[0] >= 0 and g.seg[-1] < r)
+            seen += n_in > 0
+    assert seen
+
+
+def test_segment_sums_bit_identical_to_searched_ids():
+    """The update sums over the plan's ids equal, bit for bit, a sorted
+    segment sum over the ids searched from the segment ends, in float32
+    and float64, one matrix and two."""
+    rng = np.random.default_rng(7)
+    seen = 0
+    for gp in _test_plans():
+        for g in (g for lvl in gp.groups for g in lvl):
+            n_in, r = g.src.shape[0], g.cells.shape[0]
+            if not n_in:
+                continue
+            ids = jnp.asarray(np.searchsorted(g.hi, np.arange(n_in),
+                                              side="right"))
+            for dt in (jnp.float32, jnp.float64):
+                vals = jnp.asarray(rng.standard_normal((2, n_in)), dt)
+                got = segment_sums(vals, jnp.asarray(g.seg), r)
+                want = jax.vmap(lambda v: jax.ops.segment_sum(
+                    v, ids, num_segments=r, indices_are_sorted=True))(vals)
+                np.testing.assert_array_equal(np.asarray(got),
+                                              np.asarray(want))
+                np.testing.assert_array_equal(
+                    np.asarray(segment_sums(vals[0], jnp.asarray(g.seg), r)),
+                    np.asarray(want[0]))
+            seen += 1
+    assert seen
+
+
+def test_segment_sums_has_no_search():
+    """The update sums take their ids as given: no search, loop or running
+    sum is traced into the group programs."""
+    vals = jnp.ones((2, 64), jnp.float32)
+    seg = jnp.repeat(jnp.arange(16, dtype=jnp.int32), 4)
+    fn = lambda v, s: segment_sums(v, s, 16)  # noqa: E731
+    jaxpr = str(jax.make_jaxpr(fn)(vals, seg))
+    for word in ("searchsorted", "scan[", "while[", "cumsum", "sort["):
+        assert word not in jaxpr, word
+    assert "while" not in jax.jit(fn).lower(vals, seg).as_text()
 
 
 @pytest.mark.parametrize("W", [96, 256, 384])
