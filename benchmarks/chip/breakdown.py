@@ -27,8 +27,7 @@ def breakdown(tr) -> dict:
     from chipbench import spans, trace
 
     busy = trace.busy_seconds(tr)
-    scopes = {w: spans.scope_seconds(tr, w)[0]
-              for w in spans.SCOPES + ("chol.segment_ids",)}
+    scopes = {w: spans.scope_seconds(tr, w)[0] for w in spans.SCOPES}
     scopes["kernel"] = spans.scope_seconds(tr, kernel=True)[0]
     covered = spans.scope_seconds(tr, *spans.SCOPES, kernel=True)[0]
     host = {}

@@ -1,5 +1,6 @@
-"""The chip benchmark's own library: matrix generators, traffic, work
-counts, peaks, trace reduction, the plain reference and the run harness.
+"""The chip benchmark's own library: the loader of the matrix generators
+(``generators/``), traffic, work counts, peaks, trace reduction, the plain
+reference and the run harness.
 
 It is kept apart from the solver under ``src/`` so that the yardstick
 (what is generated, counted and compared) cannot move with the program.

@@ -4,7 +4,9 @@ A cell is ``<config>.<traffic>``.  ``BENCHMARK.json`` names the cell's
 configuration file and its metrics; everything else is found by name under
 the benchmark's directory:
 
-    configs/<config>.json    sizes, source, cuts, the guarantees stated
+    configs/<config>.json    sizes, source, cuts, the guarantees stated,
+                             and the matrix class by ``generator``
+    generators/<generator>.py  the matrix class (``chipbench.matrices``)
     traffic/<traffic>.json   the parameters of the step loop
     limits/<cell>.json       the limit of each number compared, with the
                              readings it was set from
@@ -151,9 +153,9 @@ def make_ring(spec: Spec, seed: int) -> Ring:
     rng = np.random.default_rng(seed)
     sets = []
     for _ in range(int(tr["ring"]) + 1):
-        coef = matrices.coefficients(rng, cfg["generator"], cfg["grid"],
-                                     float(tr["coef_spread"]))
-        A = matrices.matrix(cfg["generator"], cfg["grid"], coef)
+        coef = matrices.coefficients(rng, cfg, float(tr["coef_spread"]),
+                                     spec.bench_dir)
+        A = matrices.matrix(cfg, coef, spec.bench_dir)
         b = rng.standard_normal((A.shape[0], int(tr["nrhs"])))
         sets.append((A, b[:, 0] if tr["nrhs"] == 1 else b))
     warm, *ring = sets
@@ -174,8 +176,7 @@ class Solver:
 def prepare(spec: Spec) -> Solver:
     from repro import core
 
-    cfg = spec.config
-    A0 = matrices.matrix(cfg["generator"], cfg["grid"])
+    A0 = matrices.matrix(spec.config, bench_dir=spec.bench_dir)
     eng = core.DeviceEngine()
     # the plan on disk holds the schedule family this engine will ask for,
     # so that only a checkout's first run builds it (no metric reads it)

@@ -4,17 +4,19 @@ refactor cells can have, planted in the timed path underneath a whole run
 the chip).  Faults of training or of several chips (half a batch averaged,
 an exchange between chips left out) do not exist in these cells; the
 nearest, half of a group's lanes left wrong, is planted instead."""
+import json
 import pathlib
 import sys
 
 import pytest
 
-sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+BENCH = pathlib.Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(BENCH))
 
-from chipbench import control, harness  # noqa: E402
+from chipbench import control, harness, matrices  # noqa: E402
 
-CELLS = ["elast3d_hex_g24.refactor", "lap2d5_g512.refactor"]
-TINY = {"elasticity_hex": 4, "laplacian_2d": 20}
+CELLS = [w["name"] for w in json.loads(
+    (BENCH.parents[1] / "BENCHMARK.json").read_text())["workloads"]]
 
 
 @pytest.fixture
@@ -28,7 +30,8 @@ def spec(request, monkeypatch, tmp_path):
     monkeypatch.setattr(harness, "OUT_DIR", tmp_path / "out")
     monkeypatch.setattr(harness, "enable_caches", lambda: None)
     s = harness.load_spec(request.param)
-    s.config["grid"] = TINY[s.config["generator"]]
+    s.config.update(matrices.generator(s.config["generator"],
+                                       s.bench_dir).TINY)
     return s
 
 

@@ -1,4 +1,4 @@
-"""The plain reference and the copied generators."""
+"""The plain reference and the matrix generators."""
 import pathlib
 import sys
 
@@ -6,9 +6,15 @@ import numpy as np
 import pytest
 import scipy.sparse as sp
 
-sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+BENCH = pathlib.Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(BENCH))
 
 from chipbench import matrices, reference  # noqa: E402
+
+#: every generator file, at the size its ``TINY`` gives
+TINY = [{"generator": p.stem, **matrices.generator(p.stem).TINY}
+        for p in sorted((BENCH / "generators").glob("*.py"))]
+HEX = matrices.generator("elasticity_hex")
 
 
 @pytest.mark.parametrize("gen,grid", [("laplacian_2d", 7),
@@ -19,19 +25,17 @@ def test_unit_coefficients_give_the_solvers_matrix(gen, grid):
     ref = sp.csc_matrix(getattr(original, gen)(grid))
     ref.sum_duplicates()
     ref.sort_indices()
-    A = matrices.matrix(gen, grid)
+    A = matrices.matrix({"generator": gen, "grid": grid})
     assert np.array_equal(A.indptr, ref.indptr)
     assert np.array_equal(A.indices, ref.indices)
     assert np.allclose(A.data, ref.data, rtol=0, atol=1e-15)
 
 
-@pytest.mark.parametrize("gen,grid", [("laplacian_2d", 9),
-                                      ("elasticity_hex", 3)])
-def test_seeded_values_keep_pattern_and_stay_spd(gen, grid):
+@pytest.mark.parametrize("cfg", TINY, ids=lambda c: c["generator"])
+def test_seeded_values_keep_pattern_and_stay_spd(cfg):
     rng = np.random.default_rng(2 ** 31 + 11)
-    A0 = matrices.matrix(gen, grid)
-    A = matrices.matrix(gen, grid,
-                        matrices.coefficients(rng, gen, grid, 1.25))
+    A0 = matrices.matrix(cfg)
+    A = matrices.matrix(cfg, matrices.coefficients(rng, cfg, 1.25))
     assert np.array_equal(A.indices, A0.indices)
     assert not np.allclose(A.data, A0.data)
     assert abs(A - A.T).max() == 0
@@ -39,7 +43,7 @@ def test_seeded_values_keep_pattern_and_stay_spd(gen, grid):
 
 
 def test_hex_element_moves_rigidly_without_strain():
-    K = matrices.hex_stiffness()
+    K = HEX.hex_stiffness()
     assert np.array_equal(K, K.T)
     ev = np.linalg.eigvalsh(K)
     assert (np.abs(ev) < 1e-12).sum() == 6 and ev[6] > 1e-2
@@ -52,7 +56,7 @@ def test_hex_element_moves_rigidly_without_strain():
 
 def _hex_by_elements(grid, coef):
     """Assemble the clamped cube element by element, the plain way."""
-    K = matrices.hex_stiffness()
+    K = HEX.hex_stiffness()
     n = 3 * grid ** 3
     A = np.zeros((n, n))
     e = 0
@@ -80,8 +84,9 @@ def _hex_by_elements(grid, coef):
 @pytest.mark.parametrize("grid", [2, 3])
 def test_hex_assembly_equals_the_element_sum(grid):
     rng = np.random.default_rng(2 ** 31 + 5)
-    coef = matrices.coefficients(rng, "elasticity_hex", grid, 1.25)
-    A = matrices.matrix("elasticity_hex", grid, coef)
+    cfg = {"generator": "elasticity_hex", "grid": grid}
+    coef = matrices.coefficients(rng, cfg, 1.25)
+    A = matrices.matrix(cfg, coef)
     assert np.allclose(A.toarray(), _hex_by_elements(grid, coef),
                        rtol=0, atol=1e-14)
     if grid == 3:
@@ -98,8 +103,9 @@ def _dense_panels(L):
         yield j, rows, L[rows, j][:, None]
 
 
-def test_factor_error_sees_exact_and_wrong_factors():
-    A = matrices.matrix("laplacian_2d", 6)
+@pytest.mark.parametrize("cfg", TINY, ids=lambda c: c["generator"])
+def test_factor_error_sees_exact_and_wrong_factors(cfg):
+    A = matrices.matrix(cfg)
     rng = np.random.default_rng(0)
     perm = rng.permutation(A.shape[0])
     Ap = A.toarray()[perm][:, perm]
@@ -111,7 +117,8 @@ def test_factor_error_sees_exact_and_wrong_factors():
 
 
 def test_rel_residual_of_nonfinite_answer_is_inf():
-    A = matrices.matrix("laplacian_2d", 4)
+    T = sp.diags([-1.0, 2.0, -1.0], [-1, 0, 1], shape=(4, 4))
+    A = sp.csc_matrix(sp.kron(T, sp.eye(4)) + sp.kron(sp.eye(4), T))
     b = np.ones(16)
     x = np.linalg.solve(A.toarray(), b)
     assert reference.rel_residual(A, x, b) < 1e-14

@@ -14,19 +14,18 @@ BENCH = pathlib.Path(__file__).resolve().parents[1]
 ROOT = BENCH.parents[1]
 sys.path.insert(0, str(BENCH))
 
-from chipbench import harness  # noqa: E402
+from chipbench import harness, matrices  # noqa: E402
 
 CELLS = [w["name"] for w in
          json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]]
-#: a tiny grid of each configuration's generator
-TINY = {"elasticity_hex": 4, "laplacian_2d": 20}
 KEYS = ["correct", "attempted", "failed", "metrics", "device", "checks"]
 
 
 @pytest.fixture
 def tiny(monkeypatch, tmp_path):
     """Float32 device values as on the chip; caches and traces in a
-    temporary directory; a spec maker that shrinks the cell's grid."""
+    temporary directory; a spec maker that shrinks the cell's matrix to
+    its generator's ``TINY`` size."""
     import jax.numpy as jnp
 
     from repro.kernels import ops as kops
@@ -38,7 +37,8 @@ def tiny(monkeypatch, tmp_path):
 
     def make(cell, **kw):
         spec = harness.load_spec(cell, **kw)
-        spec.config["grid"] = TINY[spec.config["generator"]]
+        spec.config.update(matrices.generator(spec.config["generator"],
+                                              spec.bench_dir).TINY)
         return spec
 
     return make
@@ -90,9 +90,10 @@ def test_new_config_traffic_and_metric_found_by_name(tiny, tmp_path):
     shutil.copytree(BENCH, bench, ignore=shutil.ignore_patterns(
         ".cache", ".out", "tests", "__pycache__"))
     man = json.loads((ROOT / "BENCHMARK.json").read_text())
-    (bench / "configs" / "lap2d5_g16.json").write_text(json.dumps(
-        dict(json.loads((BENCH / "configs" / "lap2d5_g512.json")
-                        .read_text()), name="lap2d5_g16", grid=16)))
+    base = json.loads((BENCH / "configs" / "lap2d5_g512.json").read_text())
+    cfg = dict(base, name="lap2d5_g16",
+               **matrices.generator(base["generator"]).TINY)
+    (bench / "configs" / "lap2d5_g16.json").write_text(json.dumps(cfg))
     (bench / "traffic" / "wide.json").write_text(json.dumps(
         {"loop": "closed", "ring": 2, "nrhs": 1, "coef_spread": 2.0,
          "check_factors": 1}))
@@ -114,7 +115,7 @@ def test_new_config_traffic_and_metric_found_by_name(tiny, tmp_path):
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(man))
     spec = harness.load_spec(cell, manifest=tmp_path / "BENCHMARK.json",
                              bench_dir=bench)
-    assert spec.config["grid"] == 16 and spec.traffic["ring"] == 2
+    assert spec.config == cfg and spec.traffic["ring"] == 2
     out = _run(spec, traced=True)
     assert out["correct"] is True
     assert out["metrics"]["steps_seen.wide"]["value"] == out["attempted"]

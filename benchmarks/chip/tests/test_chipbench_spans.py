@@ -1,7 +1,7 @@
 """The solver's spans and scopes in a trace (``chipbench.spans``): scope
 time counted once where a loop overlaps its fusions, span self time, window
 clipping, idle gaps named by the program's spans, the op_names read from a
-trace file's event metadata, and the ten readers that report them."""
+trace file's event metadata, and the nine readers that report them."""
 import pathlib
 import sys
 
@@ -13,22 +13,22 @@ sys.path.insert(0, str(BENCH))
 from chipbench import harness, spans, trace  # noqa: E402
 from chipbench.trace import Op, Trace  # noqa: E402
 
-SEGMENT_IDS = "jit(f)/chol.assemble/chol.segment_ids/while:"
+NESTED = "jit(f)/chol.assemble/chol.inner/while:"
 
 
 def _synthetic():
     """Window [100, 1100) ns, device 0, two steps' worth of spans:
 
-    - a ``while`` [100, 400) under segment_ids overlapping its fusion
-      [150, 350), a pack [400, 450) under assemble, an XLA factor
-      [450, 550), the kernel [550, 600), a level solve [900, 1000), and
-      finalize [1050, 1200) that runs past the window's end;
+    - a ``while`` [100, 400) under a scope nested in assemble, overlapping
+      its fusion [150, 350), a pack [400, 450) under assemble, an XLA
+      factor [450, 550), the kernel [550, 600), a level solve [900, 1000),
+      and finalize [1050, 1200) that runs past the window's end;
     - the host spans: a fill [100, 110), then factor [110, 600) with a
       stage and two dispatches inside it, then refine [610, 1150), past
       the window's end, holding a solve [850, 1050) with a readback
       [1020, 1050) inside it."""
-    ops = [(Op("while.4", "while.4", 100, 300, 0), SEGMENT_IDS),
-           (Op("fusion.7", "fusion.7", 150, 200, 0), SEGMENT_IDS[:-1]
+    ops = [(Op("while.4", "while.4", 100, 300, 0), NESTED),
+           (Op("fusion.7", "fusion.7", 150, 200, 0), NESTED[:-1]
             + "/body/gather:"),
            (Op("fusion.8", "fusion.8", 400, 50, 0),
             "jit(f)/chol.assemble/gather:"),
@@ -56,9 +56,9 @@ def _synthetic():
 
 def test_scope_counts_a_loop_and_its_fusions_once():
     tr = _synthetic()
-    assert spans.scope_seconds(tr, "chol.segment_ids") == (
+    assert spans.scope_seconds(tr, "chol.inner") == (
         pytest.approx(300e-9), 2)
-    # assemble holds segment_ids: [100, 400) and [400, 450)
+    # assemble holds the nested scope: [100, 400) and [400, 450)
     assert spans.scope_seconds(tr, "chol.assemble")[0] == pytest.approx(
         350e-9)
     assert spans.scope_seconds(tr, kernel=True) == (pytest.approx(50e-9), 1)
@@ -151,7 +151,7 @@ def _plane(name, lines, events, stats=()):
 
 
 def _write_trace(directory: pathlib.Path, with_program: bool) -> None:
-    tf_op = ([(10, SEGMENT_IDS)] if with_program else [])
+    tf_op = ([(10, NESTED)] if with_program else [])
     device = _plane(
         "/device:TPU:0",
         [("XLA Modules", 1000, [(3, 0, 900_000)]),
@@ -175,10 +175,10 @@ def test_attach_reads_op_names_and_spans_from_the_file(tmp_path):
     tr = trace.load(tmp_path)
     assert [o.dur for o in tr.ops] == [500.0, 200.0]
     assert spans.attach(tr, tmp_path)
-    assert tr.op_names == [SEGMENT_IDS, "jit(f)/chol.assemble/gather:"]
+    assert tr.op_names == [NESTED, "jit(f)/chol.assemble/gather:"]
     assert tr.program_spans == [("chol.dispatch", 950.0, 300.0),
                                 ("chol.readback", 1400.0, 600.0)]
-    assert spans.scope_seconds(tr, "chol.segment_ids") == (
+    assert spans.scope_seconds(tr, "chol.inner") == (
         pytest.approx(500e-9), 1)
 
 
@@ -194,7 +194,6 @@ def test_attach_refuses_another_runs_file(tmp_path):
 #: reader -> its value on ``_synthetic`` in ms per step (two steps)
 READERS = {
     "assemble_ms.refactor": 350e-6 / 2,
-    "segment_ids_ms.refactor": 300e-6 / 2,
     "xla_group_ms.refactor": 100e-6 / 2,
     "finalize_ms.refactor": 50e-6 / 2,
     "level_solve_ms.refactor": 100e-6 / 2,

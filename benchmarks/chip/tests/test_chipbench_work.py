@@ -5,9 +5,14 @@ import sys
 import numpy as np
 import pytest
 
-sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+BENCH = pathlib.Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(BENCH))
 
 from chipbench import matrices, work  # noqa: E402
+
+#: every generator file, at the size its ``TINY`` gives
+TINY = [{"generator": p.stem, **matrices.generator(p.stem).TINY}
+        for p in sorted((BENCH / "generators").glob("*.py"))]
 
 
 def _groups(sym, sched):
@@ -20,13 +25,12 @@ def _groups(sym, sched):
 
 
 @pytest.mark.parametrize("bucket", ["batch", "fused"])
-@pytest.mark.parametrize("gen,grid", [("elasticity_hex", 4),
-                                      ("laplacian_2d", 24)])
-def test_column_ops_equal_group_flop_stats(gen, grid, bucket):
+@pytest.mark.parametrize("cfg", TINY, ids=lambda c: c["generator"])
+def test_column_ops_equal_group_flop_stats(cfg, bucket):
     from repro.core import symbolic_pipeline
     from repro.core.schedule import build_schedule, group_flop_stats
 
-    sym, _ = symbolic_pipeline(matrices.matrix(gen, grid))
+    sym, _ = symbolic_pipeline(matrices.matrix(cfg))
     sched = build_schedule(sym, bucket=bucket)
     ops = [sum(work.supernode_column_ops(w, m) for w, m in ext)
            for ext in _groups(sym, sched)]
