@@ -34,7 +34,15 @@ per-supernode, per-entry or per-column loop:
                    device: one span per increment of ``stats["transfers_out"]``
     chol.solve     one level-scheduled device solve
     chol.refine    one refined solve; its ``chol.solve`` children apply the
-                   factor, the rest (its self time) is host residual work
+                   factor, the rest (its self time) is host residual work.
+                   Its argument ``solves`` counts the times it applied the
+                   factor, the raw solve included; the factor keeps the same
+                   count per refined solve in ``CholeskyFactor.refine_solves``,
+                   beside the residual trajectories of ``ir_history``
+    chol.gmres     one GMRES cycle inside ``chol.refine`` (reached where
+                   stationary refinement stalls); its ``chol.solve`` children
+                   apply the factor, its self time is the orthogonalization,
+                   the least-squares update and the true residuals
 
 Device scopes (``scope``) are ``jax.named_scope`` s applied while a program
 is traced, so they cost nothing at run time.  They land in each operation's

@@ -159,8 +159,10 @@ class CholeskyFactor:
     # perturbations, a shift, or a float32 device factor
     guard_report: object | None = None
     guard_A: object | None = None
-    # relative-residual trajectory of every refined solve on this factor
+    # relative-residual trajectory of every refined solve on this factor,
+    # and the number of times each applied the factor (raw solve included)
     ir_history: list = field(default_factory=list)
+    refine_solves: list = field(default_factory=list)
 
     def L_dense(self) -> np.ndarray:
         """Assemble the full dense L (for small-n validation only)."""
@@ -214,9 +216,10 @@ class CholeskyFactor:
                     "carries no guard_A (factor with guard= to record it)"
                 )
             from repro.core.refine import refine_solve
-            x, hist = refine_solve(self, self.guard_A, b,
-                                   backend=backend, engine=engine)
+            x, hist, solves = refine_solve(self, self.guard_A, b,
+                                           backend=backend, engine=engine)
             self.ir_history.append(hist)
+            self.refine_solves.append(solves)
             if self.guard_report is not None:
                 self.guard_report.ir_history.append(hist)
             return x

@@ -126,11 +126,13 @@ def block_refine(matvec, psolve, B, X=None, *, tol=1e-12, max_steps=10):
     ``B`` is (..., n, k); residuals are taken per column over axis -2.  A
     step is kept, column by column, only where it at least halves that
     column's residual, and the loop stops when every column is at ``tol``
-    or no column contracted.  This is all a float32 device factor of an SPD
-    matrix needs (each step gains about -log10(kappa * eps32) digits); a
-    perturbed factor of an indefinite matrix stalls here and finishes in
-    GMRES (``refine_solve``).  Returns ``(X, res, hist)``: per-column
-    relative residuals and the max-over-columns trajectory."""
+    or no column contracted.  A float32 device factor of a well-conditioned
+    SPD matrix needs nothing more (each step gains about
+    -log10(kappa * eps32) digits); an ill-conditioned SPD matrix, whose
+    steps contract too little, and a perturbed factor of an indefinite
+    matrix stall here and finish in GMRES (``refine_solve``).  Returns
+    ``(X, res, hist)``: per-column relative residuals and the
+    max-over-columns trajectory."""
     B = np.asarray(B, dtype=np.float64)
     X = np.asarray(psolve(B) if X is None else X, dtype=np.float64)
     bn = np.maximum(np.linalg.norm(B, axis=-2), np.finfo(np.float64).tiny)
@@ -162,17 +164,21 @@ def refine_solve(F, A, b, *, x0=None, tol=1e-12, max_iter=None,
     exact factorization of a *perturbed* matrix A + E and its raw solve is
     only a preconditioner for A.  Block iterative refinement over all RHS
     columns comes first (``block_refine``; it alone converges when A is SPD
-    and the factor's error is small), then each column still above ``tol``
-    gets one more guarded IR step and right-preconditioned full-basis
-    GMRES with ``M^{-1} = F.solve``: stationary IR provably stalls when A is
-    indefinite — a pivot perturbed from d <= 0 up to t > 0 contributes an
-    iteration factor |t - d| / t >= 1 — while GMRES with a rank-p
-    perturbation preconditioner terminates in at most p + 1 iterations.
+    and well conditioned next to the factor's precision), then each column
+    still above ``tol`` gets one more guarded IR step and flexible GMRES
+    with ``M^{-1} = F.solve`` (``_refine_one``): stationary IR provably
+    stalls when A is indefinite — a pivot perturbed from d <= 0 up to t > 0
+    contributes an iteration factor |t - d| / t >= 1 — while GMRES with a
+    rank-p perturbation preconditioner terminates in at most p + 1
+    iterations; and it stalls too when kappa(A) * eps32 nears 1, where
+    GMRES still converges.
 
-    Returns ``(x, hist)`` where ``hist`` is the relative-residual trajectory
-    (max over RHS columns for multi-RHS ``b``).
+    Returns ``(x, hist, solves)``: ``hist`` is the relative-residual
+    trajectory (max over RHS columns for multi-RHS ``b``), ``solves`` the
+    number of times the factor was applied, the raw solve included.  The
+    ``chol.refine`` span carries ``solves`` as its argument.
     """
-    with counters.span("refine"):
+    with counters.span("refine") as span:
         b = np.asarray(b, dtype=np.float64)
         squeeze = b.ndim == 1
         B = b[:, None] if squeeze else b
@@ -187,8 +193,11 @@ def refine_solve(F, A, b, *, x0=None, tol=1e-12, max_iter=None,
                 max_iter = int(min(B.shape[0], max(2 * p + 30, 100)))
             else:
                 max_iter = int(min(B.shape[0], 300))
+        solves = 0
 
         def psolve(v):
+            nonlocal solves
+            solves += 1
             return np.asarray(
                 F.solve(v, backend=backend, engine=engine, refine=False)
             )
@@ -210,18 +219,22 @@ def refine_solve(F, A, b, *, x0=None, tol=1e-12, max_iter=None,
             hist += [max(max(h[min(i, len(h) - 1)] for h in hists),
                          done if i == depth - 1 else 0.0)
                      for i in range(depth)]
-        return (X[:, 0] if squeeze else X), hist
+        span.set_metadata(solves=solves)
+        return (X[:, 0] if squeeze else X), hist, solves
 
 
 def _refine_one(A, b, x0, psolve, tol, max_iter):
-    """Single-RHS refinement: 1 guarded IR step, then restarted
-    right-preconditioned GMRES cycles."""
+    """Single-RHS refinement: 1 guarded IR step, then restarted flexible
+    GMRES cycles (Saad, SIAM J. Sci. Comput. 14, 1993), each in a
+    ``chol.gmres`` span.  Returns the iterate with the smallest true
+    residual seen and ``hist``, whose last entry is that residual."""
     bnorm = float(np.linalg.norm(b))
     if bnorm == 0.0:
         return np.zeros_like(b), [0.0]
     x = psolve(b) if x0 is None else x0.astype(np.float64).copy()
     r = b - A @ x
-    hist = [float(np.linalg.norm(r)) / bnorm]
+    rnorm = float(np.linalg.norm(r))
+    hist = [rnorm / bnorm]
     if hist[-1] <= tol:
         return x, hist
     # one stationary IR step — free when E is tiny relative to an SPD A, but
@@ -231,49 +244,77 @@ def _refine_one(A, b, x0, psolve, tol, max_iter):
     # residual, so letting IR blow r up by 1e5 costs 1e5 in final accuracy
     xt = x + psolve(r)
     rt = b - A @ xt
-    if float(np.linalg.norm(rt)) < float(np.linalg.norm(r)):
-        x, r = xt, rt
-    hist.append(float(np.linalg.norm(r)) / bnorm)
+    rtnorm = float(np.linalg.norm(rt))
+    if rtnorm < rnorm:
+        x, r, rnorm = xt, rt, rtnorm
+    hist.append(rnorm / bnorm)
     if hist[-1] <= tol:
         return x, hist
-    # restarted right-preconditioned GMRES on the residual equation: each
-    # cycle's attainable accuracy is ~eps * kappa relative to ITS OWN r0, so
-    # restarting from the corrected iterate compounds the reduction past the
-    # single-cycle floating-point floor
+    # restarted GMRES on the residual equation: each cycle's attainable
+    # accuracy is ~eps * kappa relative to ITS OWN r0, so restarting from
+    # the corrected iterate compounds the reduction past the single-cycle
+    # floating-point floor.  Flexible: the cycle keeps Z[j] = M^-1 V[j] and
+    # updates x += Z y, exact for any preconditioner — a float32 solve of an
+    # ill-conditioned matrix is too far from linear for x += M^-1 (V y)
     for _cycle in range(4):
-        beta = float(np.linalg.norm(r))
-        V = [r / beta]
-        H = np.zeros((max_iter + 1, max_iter))
-        e1 = np.zeros(max_iter + 1)
-        e1[0] = beta
-        y, niter = None, 0
-        for j in range(max_iter):
-            w = A @ psolve(V[j])
-            for i in range(j + 1):
-                H[i, j] = float(V[i] @ w)
-                w = w - H[i, j] * V[i]
-            # one reorthogonalization pass: single-pass MGS loses
-            # orthogonality over ~100 iterations and breaks the
-            # exact-termination property the rank-p argument relies on
-            for i in range(j + 1):
-                c = float(V[i] @ w)
-                H[i, j] += c
-                w = w - c * V[i]
-            H[j + 1, j] = float(np.linalg.norm(w))
-            niter = j + 1
-            y = np.linalg.lstsq(H[:j + 2, :j + 1], e1[:j + 2], rcond=None)[0]
-            res = float(np.linalg.norm(e1[:j + 2] - H[:j + 2, :j + 1] @ y))
-            hist.append(res / bnorm)
-            if res <= tol * bnorm or H[j + 1, j] <= 1e-300:
-                break
-            V.append(w / H[j + 1, j])
-        if y is None:
-            break
-        prev = float(np.linalg.norm(r))
-        z = np.stack(V[:niter], axis=1) @ y
-        x = x + psolve(z)
-        r = b - A @ x
-        hist[-1] = float(np.linalg.norm(r)) / bnorm  # true, not Arnoldi, resid
-        if hist[-1] <= tol or not float(np.linalg.norm(r)) < 0.5 * prev:
+        start = rnorm
+        with counters.span("gmres"):
+            x, r, rnorm = _fgmres_cycle(A, b, x, r, rnorm, psolve,
+                                        tol * bnorm, max_iter, hist)
+        hist[-1] = rnorm / bnorm  # true, not Arnoldi, residual
+        if hist[-1] <= tol or not rnorm < 0.5 * start:
             break
     return x, hist
+
+
+def _fgmres_cycle(A, b, x, r, rnorm, psolve, atol, max_iter, hist):
+    """One flexible GMRES cycle from ``x`` (true residual ``r``, of norm
+    ``rnorm``); appends each iteration's Arnoldi residual to ``hist``.
+
+    The Arnoldi residual tracks the true one until float64 rounding floors
+    the latter (about eps * ||A|| ||Z y||, relative to the cycle's own
+    start).  Once the Arnoldi residual claims a fourfold fall since the
+    last true reading, the true residual is read, and the cycle stops
+    where it has not halved: further iterations would be solves spent past
+    that floor.  Returns ``(x, r, rnorm)`` for the iterate with the
+    smallest true residual read, never worse than the input."""
+    bnorm = float(np.linalg.norm(b))
+    x0, best = x, (x, r, rnorm)
+    V = [r / rnorm]
+    Z = []
+    H = np.zeros((max_iter + 1, max_iter))
+    e1 = np.zeros(max_iter + 1)
+    e1[0] = rnorm
+    last = rnorm
+    for j in range(max_iter):
+        Z.append(psolve(V[j]))
+        w = A @ Z[j]
+        for i in range(j + 1):
+            H[i, j] = float(V[i] @ w)
+            w = w - H[i, j] * V[i]
+        # one reorthogonalization pass: single-pass MGS loses
+        # orthogonality over ~100 iterations and breaks the
+        # exact-termination property the rank-p argument relies on
+        for i in range(j + 1):
+            c = float(V[i] @ w)
+            H[i, j] += c
+            w = w - c * V[i]
+        H[j + 1, j] = float(np.linalg.norm(w))
+        y = np.linalg.lstsq(H[:j + 2, :j + 1], e1[:j + 2], rcond=None)[0]
+        est = float(np.linalg.norm(e1[:j + 2] - H[:j + 2, :j + 1] @ y))
+        hist.append(est / bnorm)
+        claim = est <= 0.25 * last
+        end = est <= atol or H[j + 1, j] <= 1e-300 or j == max_iter - 1
+        if claim or end:
+            xt = x0 + np.stack(Z, axis=1) @ y
+            rt = b - A @ xt
+            tn = float(np.linalg.norm(rt))
+            if tn < best[2]:
+                best = (xt, rt, tn)
+            if claim and not tn < 0.5 * last:
+                return best
+            last = tn
+        if end:
+            break
+        V.append(w / H[j + 1, j])
+    return best

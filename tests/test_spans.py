@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
+from conftest import ipm_normal_3d
 from repro.core import DeviceEngine, PlanCache, cholesky, counters
 from repro.core import device_store
 from repro.kernels import fused
@@ -148,3 +149,37 @@ def test_kernel_carries_its_name():
     (call,) = calls(jaxpr.jaxpr)
     assert call.params["name"] == fused.KERNEL_NAME
     assert fused.KERNEL_NAME == "fused_factor_syrk"
+
+
+def test_instrumentation_docstring_names_every_span_and_counter():
+    doc = counters.__doc__
+    for name in HOST_SPANS + ("gmres",):
+        assert counters.SPAN_PREFIX + name in doc, name
+    assert "refine_solves" in doc and "``solves``" in doc
+
+
+def test_gmres_cycles_and_the_solve_count_are_recorded(tmp_path,
+                                                        monkeypatch):
+    """An ill-conditioned SPD matrix finishes its refinement in GMRES:
+    each cycle is a ``chol.gmres`` span inside ``chol.refine`` with device
+    solves inside it, and ``chol.refine``'s ``solves`` argument is the
+    factor's own count and the number of solves it holds."""
+    monkeypatch.setattr(kops, "device_dtype", lambda: jnp.float32)
+    A, b = ipm_normal_3d(8, 5e4, seed=3)
+    F = cholesky(A, plan=PlanCache().get(A), device_engine=DeviceEngine())
+    F.solve(b, backend="device", refine=False)      # compile the solve
+    with jax.profiler.trace(str(tmp_path)):
+        F.solve(b, backend="device")
+    files = sorted(pathlib.Path(tmp_path).rglob("*.xplane.pb"))
+    events = [ev for plane in ProfileData.from_file(str(files[-1])).planes
+              if plane.name.startswith("/host:") for line in plane.lines
+              for ev in line.events]
+    of = {k: [(ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats))
+              for ev in events if ev.name == counters.SPAN_PREFIX + k]
+          for k in ("refine", "gmres", "solve")}
+    (refine,) = of["refine"]
+    assert of["gmres"] and all(_within(("", *g[:2]), [("", *refine[:2])])
+                               for g in of["gmres"])
+    assert all(any(_within(("", *s[:2]), [("", *g[:2])])
+                   for s in of["solve"]) for g in of["gmres"])
+    assert refine[2]["solves"] == F.refine_solves[-1] == len(of["solve"])
